@@ -96,14 +96,14 @@ def measure_sharded(n: int, sweep: str, fuse_residual: bool,
 
     from repro.core import detection
     from repro.launch import hlo_analysis
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.solvers.convdiff import Stencil
     from repro.solvers.fixed_point import SolverConfig, make_sharded_solver
     from repro.solvers.partition import process_grid
 
     ndev = len(jax.devices())
     px, py = process_grid(ndev)
-    mesh = compat_make_mesh((px, py), ("data", "model"))
+    mesh = make_mesh((px, py), ("data", "model"))
     st = Stencil.for_contraction(n, 1.0, (1.0, 1.0, 1.0), rho=0.95)
     mon = detection.for_mode("pfait", eps_tilde=1e-6, margin=10.0, staleness=2)
     cfg = SolverConfig(stencil=st, monitor=mon, inner_sweeps=inner_sweeps,
@@ -161,6 +161,9 @@ def bench_sharded(n: int, inner_sweeps: int = 1, runner=None):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny sizes + relaxed thresholds (CI)")

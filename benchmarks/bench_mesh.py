@@ -321,6 +321,9 @@ def _run(specs, runner=None):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="small sizes + reduced matrix (CI)")

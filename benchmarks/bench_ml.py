@@ -208,6 +208,9 @@ def _wall_comparison(rows) -> Dict:
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="small sizes + reduced matrix (CI)")

@@ -278,6 +278,9 @@ WHATIF_TOPOLOGIES = ("flat-nonblocking", "flat-blocking", "butterfly",
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="reduced repeats + measured matrix (CI)")

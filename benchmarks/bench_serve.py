@@ -184,6 +184,9 @@ def _run(specs):
 
 def main():
     """CLI: run the load cell + rate sweep, write the report, assert."""
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="small load + 2-point sweep (CI)")

@@ -192,12 +192,12 @@ def _driver_reference(n, p, eps, max_outer, st, b, x0, r, rtol) -> Dict:
     import jax
 
     from repro.core import detection
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_mesh
     from repro.solvers.fixed_point import SolverConfig, make_sharded_solver
     from repro.solvers.partition import process_grid
 
     px, py = process_grid(p)
-    mesh2d = compat_make_mesh((px, py), ("data", "model"))
+    mesh2d = make_mesh((px, py), ("data", "model"))
     mon = detection.MonitorConfig(mode="sync", eps=eps, staleness=0, ord=2.0)
     dcfg = SolverConfig(stencil=st, monitor=mon, inner_sweeps=1,
                         max_outer=max_outer, sweep="jacobi",
@@ -385,6 +385,9 @@ def _run(specs, runner=None):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="small sizes + reduced matrix (CI)")

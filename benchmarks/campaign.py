@@ -209,6 +209,20 @@ def _fork_is_safe() -> bool:
     return not getattr(xb, "_backends", None)
 
 
+def _cells_need_chip(specs: Sequence[Dict]) -> bool:
+    """True when a cell touches the JAX device (its kind declares the
+    ``jax`` environment) and that device is a TPU.  A chip belongs to one
+    process: the parent holds it once JAX is up, so a pool child could not
+    open it — such campaigns run inline."""
+    from benchmarks.common import CELL_KINDS
+
+    if not any("jax" in CELL_KINDS[s["kind"]].env for s in specs):
+        return False
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
 def _exec_cell(spec: Dict) -> Tuple[Dict, float]:
     """Pool worker entry: run one cell through the kind registry."""
     from benchmarks.common import run_cell_spec
@@ -314,7 +328,8 @@ def run_campaign(
     flush_report()
 
     workers = cfg.workers if cfg.workers is not None else (os.cpu_count() or 1)
-    inline = cfg.executor == "inline" or workers == 0 or len(pending) <= 1
+    inline = (cfg.executor == "inline" or workers == 0 or len(pending) <= 1
+              or _cells_need_chip([specs[i] for i in pending]))
     out.executor = "inline" if inline else cfg.executor
     out.workers = 0 if inline else min(workers, len(pending))
 

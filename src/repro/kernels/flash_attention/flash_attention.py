@@ -1,7 +1,7 @@
 """Blocked online-softmax (flash) attention — Pallas TPU.
 
-Grid: (B·N·P heads, q-blocks); each program streams kv-blocks with windowed
-``pl.load`` from HBM, keeping the f32 (m, l, acc) accumulators in registers/
+Grid: (B·N·P heads, q-blocks); each program streams kv-blocks by windowed
+ref indexing from HBM, keeping the f32 (m, l, acc) accumulators in registers/
 VMEM across the inner ``fori_loop``.  MXU-aligned 128×head_dim tiles.
 
 Causal **block skipping**: the kv loop runs only over blocks intersecting
@@ -20,13 +20,6 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _ANY = pltpu.ANY
-except Exception:  # pragma: no cover
-    _ANY = None
 
 NEG_INF = -1e30
 
@@ -54,10 +47,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, block_q: int,
 
     def body(jb, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (kv_row, pl.ds(jb * block_kv, block_kv),
-                            pl.ds(0, H))).astype(jnp.float32)
-        v = pl.load(v_ref, (kv_row, pl.ds(jb * block_kv, block_kv),
-                            pl.ds(0, H))).astype(jnp.float32)
+        kv = (kv_row, pl.ds(jb * block_kv, block_kv), slice(None))
+        k = k_ref[kv].astype(jnp.float32)
+        v = v_ref[kv].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # [bq, bkv]
         kv_pos = jb * block_kv + jax.lax.iota(jnp.int32, block_kv)
         mask = jnp.ones((block_q, block_kv), jnp.bool_)
@@ -114,8 +106,8 @@ def flash_attention_flat(
         grid=(BH, Sq // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, H), lambda bh, iq: (bh, iq, 0)),
-            pl.BlockSpec(memory_space=_ANY),
-            pl.BlockSpec(memory_space=_ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, block_q, H), lambda bh, iq: (bh, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, Sq, H), q.dtype),

@@ -1,487 +1,392 @@
 """Fused 7-point convection–diffusion sweep + local residual norm — Pallas TPU.
 
 The paper's hot loop.  GPU implementations make two passes over the grid
-(relaxation sweep, then residual norm for the detection layer); on TPU we
-tile the (x, y) plane with the full z-pencil resident (the paper's
-decomposition keeps z local, §4.1) and produce BOTH the swept block and the
-block's residual-norm partial in one HBM pass — the stencil is memory-bound,
-so fusing the detection pass is a ~2× traffic saving (validated in
-EXPERIMENTS.md §Perf).  Two sweep flavours are fused:
+(relaxation sweep, then residual norm for the detection layer); here one
+grid pass produces BOTH the swept block and the block's residual-norm
+partials — the stencil is memory-bound, so fusing the detection pass saves
+a second read of the field.  Two sweep flavours are fused:
 
-* ``fused_sweep_residual``       — Jacobi sweep (±1 halo window);
-* ``fused_rbgs_sweep_residual``  — the paper's hybrid red-black GS sweep
-  (±2 halo window: each tile recomputes its ring's color-0 updates locally,
-  so the two-color dependency never crosses tiles and the sweep stays a
-  single grid pass).
+* ``fused_sweep_residual*``       — Jacobi sweep (±1 plane window);
+* ``fused_rbgs_sweep_residual*``  — the paper's hybrid red-black GS sweep
+  (±2 plane window: each slab recomputes the colour-0 updates of its two
+  neighbouring planes locally, so the two-colour dependency never crosses
+  grid steps and the sweep stays a single grid pass).
 
 Both report the residual of the *input* state (``b − A x_in``), i.e. the
 detection contribution is one sweep staler than a dedicated post-sweep pass
 — exactly the trade the paper's protocol-free detection is built to absorb.
 
-Halo handling: the ghosted input stays in HBM (``memory_space=ANY``) and
-each (x, y) tile loads its overlapping ``(tx+2, ty+2, bz+2)`` window with an
-explicit ``pl.load`` + ``pl.ds`` (windowed DMA) — overlapping reads are not
-expressible with non-overlapping ``BlockSpec`` tiling.  Outputs use regular
-blocked specs.  The z-pencil (last dim, padded grid) keeps lane dimension
-≥ 128 for VPU efficiency at production sizes (bz = n + 2 ≥ 514).
+Layout.  A block is ``(bx, by, bz)`` with z on the 128-wide lanes and y on
+the sublanes.  The grid walks x-slabs of ``tx`` whole ``(by, bz)`` planes,
+so every y/z neighbour of a slab cell is in the slab: the ±1 shifts along
+sublanes/lanes are ``pltpu.roll`` rotations whose wrapped row/column is
+replaced by the face halo.  The x neighbours outside the slab arrive as
+extra single-plane blocks (index clamped into the block; the x∓ face halo
+substitutes at the block edge), so every input is a tile-legal block that
+Pallas pipelines HBM→VMEM itself.  ``tx`` follows from the plane size (see
+``_slab_planes``), which keeps VMEM bounded for blocks up to 512² planes.
+The seven stencil coefficients and the checkerboard phase live in SMEM;
+each grid step writes its residual partial into its own lane-dense
+``(8, 128)`` output tile.
+
+The ghosted-layout entries (``fused_sweep_residual`` on a ±1 ghosted
+block, ``fused_rbgs_sweep_residual`` on a ±2 one) unpack the ghost layers
+into face planes and run the same kernels.
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU memory spaces (fall back gracefully off-TPU)
-    from jax.experimental.pallas import tpu as pltpu
-
-    _ANY = pltpu.ANY
-except Exception:  # pragma: no cover
-    _ANY = None
-
-
-def _stencil_off(w, xm, xp, ym, yp, zm, zp):
-    """Off-diagonal apply over a ghosted window: (sx, sy, sz) → (sx−2, sy−2, sz−2)."""
-    return (
-        xm * w[:-2, 1:-1, 1:-1]
-        + xp * w[2:, 1:-1, 1:-1]
-        + ym * w[1:-1, :-2, 1:-1]
-        + yp * w[1:-1, 2:, 1:-1]
-        + zm * w[1:-1, 1:-1, :-2]
-        + zp * w[1:-1, 1:-1, 2:]
-    )
+#: VMEM bytes one x-slab may take; with its halo planes, double buffering
+#: and the stencil temporaries a kernel stays inside ``_VMEM_LIMIT``
+_SLAB_BYTES = 1 << 20
+#: most planes per slab: the per-plane loop is unrolled in the kernel body
+_MAX_PLANES = 8
+#: scoped VMEM per kernel: the RB-GS kernel on 512² planes needs more than
+#: the 16 MiB default and fits 32 MiB (v5e compile); v5e has 128 MiB
+_VMEM_LIMIT = 48 << 20
+#: partials tile: one lane-dense f32 (8, 128) block per grid step
+_PART = (8, 128)
 
 
-def _kernel(g_ref, b_ref, coef_ref, new_ref, res_ref, *, op: str, linf: bool,
-            tx: int, ty: int):
+def _slab_planes(bx: int, by: int, bz: int, itemsize: int) -> int:
+    """Planes per grid step: the largest divisor of ``bx`` whose slab fits
+    ``_SLAB_BYTES`` (at least one plane, at most ``_MAX_PLANES``)."""
+    cap = max(1, min(_MAX_PLANES, _SLAB_BYTES // (by * bz * itemsize)))
+    return max(d for d in range(1, min(cap, bx) + 1) if bx % d == 0)
+
+
+def _roll(v, shift: int, axis: int):
+    """``jnp.roll`` along a tiled axis (non-negative shift; no-op on a
+    length-1 axis).  The shift is an i32, the rotate's operand type, also
+    when x64 is enabled."""
+    shift %= v.shape[axis]
+    return pltpu.roll(v, np.int32(shift), axis) if shift else v
+
+
+def _plane_off(c, xm, xp, ym, yp, zm, zp, k):
+    """Off-diagonal apply on one x-plane ``c`` (by, bz): ``xm``/``xp`` are
+    the neighbouring planes, ``ym``/``yp`` the (1, bz) y-halo rows and
+    ``zm``/``zp`` the (by, 1) z-halo columns of this plane.  Terms are
+    summed in the order of ``solvers.jacobi.offdiag_apply``."""
+    by, bz = c.shape
+    row = jax.lax.broadcasted_iota(jnp.int32, c.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, c.shape, 1)
+    vym = jnp.where(row == 0, ym, _roll(c, 1, 0))
+    vyp = jnp.where(row == by - 1, yp, _roll(c, by - 1, 0))
+    vzm = jnp.where(col == 0, zm, _roll(c, 1, 1))
+    vzp = jnp.where(col == bz - 1, zp, _roll(c, bz - 1, 1))
+    return (k[1] * xm + k[2] * xp + k[3] * vym + k[4] * vyp
+            + k[5] * vzm + k[6] * vzp)
+
+
+def _coefs(c_ref, dtype):
+    return [c_ref[q].astype(dtype) for q in range(7)]
+
+
+def _accumulate(acc, r, linf: bool):
+    """Elementwise running max|r| / Σr² (f32) over the slab's planes."""
+    v = jnp.abs(r).astype(jnp.float32) if linf else (r * r).astype(jnp.float32)
+    if acc is None:
+        return v
+    return jnp.maximum(acc, v) if linf else acc + v
+
+
+def _write_partial(res_ref, acc, linf: bool):
+    part = jnp.max(acc) if linf else jnp.sum(acc)
+    res_ref[...] = jnp.full(res_ref.shape, part, jnp.float32)
+
+
+def _jacobi_kernel(c_ref, x_ref, xm_ref, xp_ref, gxm_ref, gxp_ref, gym_ref,
+                   gyp_ref, gzm_ref, gzp_ref, b_ref, *out_refs, sweep: bool,
+                   linf: bool, nx: int):
+    """Jacobi sweep (``sweep``) or residual-only pass over one x-slab."""
     i = pl.program_id(0)
-    j = pl.program_id(1)
-    bz2 = g_ref.shape[2]
-    # windowed load of the ghosted tile (overlapping halo window)
-    g = pl.load(
-        g_ref,
-        (pl.ds(i * tx, tx + 2), pl.ds(j * ty, ty + 2), pl.ds(0, bz2)),
-    )
-    b = b_ref[...]
-    c = coef_ref[...]
-    diag, xm, xp, ym, yp, zm, zp = c[0], c[1], c[2], c[3], c[4], c[5], c[6]
-    off = _stencil_off(g, xm, xp, ym, yp, zm, zp)
-    r = b - (diag * g[1:-1, 1:-1, 1:-1] + off)
-    if op == "sweep":
-        new_ref[...] = (b - off) / diag
-    else:  # residual-only pass keeps the field unchanged
-        new_ref[...] = g[1:-1, 1:-1, 1:-1]
-    if linf:
-        res_ref[0, 0] = jnp.max(jnp.abs(r)).astype(jnp.float32)
-    else:
-        res_ref[0, 0] = jnp.sum((r * r).astype(jnp.float32))
+    tx = x_ref.shape[0]
+    dtype = x_ref.dtype
+    k = _coefs(c_ref, dtype)
+    first = jnp.where(i == 0, gxm_ref[0], xm_ref[0])
+    last = jnp.where(i == nx - 1, gxp_ref[0], xp_ref[0])
+    acc = None
+    for t in range(tx):
+        c = x_ref[t]
+        off = _plane_off(c, first if t == 0 else x_ref[t - 1],
+                         last if t == tx - 1 else x_ref[t + 1],
+                         gym_ref[t], gyp_ref[t], gzm_ref[t], gzp_ref[t], k)
+        b = b_ref[t]
+        acc = _accumulate(acc, b - (k[0] * c + off), linf)
+        if sweep:
+            out_refs[0][t] = (b - off) / k[0]
+    _write_partial(out_refs[-1], acc, linf)
 
 
-def _rbgs_kernel(g_ref, b_ref, coef_ref, oxy_ref, new_ref, res_ref, *,
-                 linf: bool, tx: int, ty: int, bx: int, by: int):
-    """Single-pass hybrid red-black GS sweep fused with the pre-sweep residual.
-
-    Input is the twice-padded ghosted block (±2 halo in x/y so the tile can
-    redo its ring's color-0 updates instead of waiting on neighbour tiles —
-    cross-tile color-1 dependencies become local recompute) and the ±1
-    zero-padded rhs.  The residual shares the first off-diagonal apply, so
-    the whole hybrid sweep + detection contribution is one HBM pass."""
+def _rbgs_kernel(c_ref, ph_ref, x_ref, xm2_ref, xm1_ref, xp1_ref, xp2_ref,
+                 gxm_ref, gxp_ref, gym_ref, gym_m_ref, gym_p_ref, gyp_ref,
+                 gyp_m_ref, gyp_p_ref, gzm_ref, gzm_m_ref, gzm_p_ref, gzp_ref,
+                 gzp_m_ref, gzp_p_ref, b_ref, bm_ref, bp_ref, new_ref, res_ref,
+                 *, linf: bool, bx: int):
+    """Hybrid red-black GS sweep fused with the pre-sweep residual over one
+    x-slab.  Planes ``x0-2 … x0+tx+1`` are in view: the slab's neighbouring
+    planes ``x0-1``/``x0+tx`` get their colour-0 update recomputed here
+    (ghost planes stay frozen), so colour 1 on the slab sees same-sweep
+    colour-0 values without waiting on another grid step."""
     i = pl.program_id(0)
-    j = pl.program_id(1)
-    bz2 = g_ref.shape[2]
-    bz = bz2 - 2
-    w = pl.load(
-        g_ref,
-        (pl.ds(i * tx, tx + 4), pl.ds(j * ty, ty + 4), pl.ds(0, bz2)),
-    )
-    bw = pl.load(
-        b_ref,
-        (pl.ds(i * tx, tx + 2), pl.ds(j * ty, ty + 2), pl.ds(0, bz)),
-    )
-    c = coef_ref[...]
-    diag, xm, xp, ym, yp, zm, zp = c[0], c[1], c[2], c[3], c[4], c[5], c[6]
-    off_w = _stencil_off(w, xm, xp, ym, yp, zm, zp)    # (tx+2, ty+2, bz)
-    x_w = w[1:-1, 1:-1, 1:-1]                          # matching centres
-    # block coords of window positions (−1 … t+0/+1) → checkerboard + realness
-    shp = (tx + 2, ty + 2, bz)
-    gx = jax.lax.broadcasted_iota(jnp.int32, shp, 0) + i * tx - 1
-    gy = jax.lax.broadcasted_iota(jnp.int32, shp, 1) + j * ty - 1
-    gz = jax.lax.broadcasted_iota(jnp.int32, shp, 2)
-    parity = jnp.mod(gx + gy + gz + oxy_ref[0], 2)
-    real = (gx >= 0) & (gx < bx) & (gy >= 0) & (gy < by)
-    # color 0 over tile + ring (ghost ring stays frozen via the real mask)
-    upd0 = jnp.where((parity == 0) & real, (bw - off_w) / diag, x_w)
-    w1 = w.at[1:-1, 1:-1, 1:-1].set(upd0)
-    # color 1 on the tile proper, seeing same-sweep color-0 values
-    off1 = _stencil_off(w1, xm, xp, ym, yp, zm, zp)[1:-1, 1:-1, :]
-    b_t = bw[1:-1, 1:-1, :]
-    new1 = (b_t - off1) / diag
-    new_ref[...] = jnp.where(parity[1:-1, 1:-1, :] == 1, new1,
-                             upd0[1:-1, 1:-1, :])
-    r = b_t - (diag * x_w[1:-1, 1:-1, :] + off_w[1:-1, 1:-1, :])
-    if linf:
-        res_ref[0, 0] = jnp.max(jnp.abs(r)).astype(jnp.float32)
-    else:
-        res_ref[0, 0] = jnp.sum((r * r).astype(jnp.float32))
+    tx = x_ref.shape[0]
+    x0 = i * tx
+    dtype = x_ref.dtype
+    k = _coefs(c_ref, dtype)
+    diag = k[0]
+    _, by, bz = x_ref.shape
+    yz = (jax.lax.broadcasted_iota(jnp.int32, (by, bz), 0)
+          + jax.lax.broadcasted_iota(jnp.int32, (by, bz), 1) + ph_ref[0])
 
+    def even(gx):
+        """Colour-0 mask of the plane at global row ``gx``."""
+        return jnp.bitwise_and(yz + gx, 1) == 0
 
-@functools.partial(jax.jit, static_argnames=("tile", "linf", "interpret"))
-def fused_rbgs_sweep_residual(
-    g2: jax.Array,             # [(bx+4), (by+4), (bz+2)] twice-padded block
-    b2: jax.Array,             # [bx+2, by+2, bz] rhs, zero-padded ±1 in x/y
-    stencil_coefs: jax.Array,  # [7] (diag, xm, xp, ym, yp, zm, zp)
-    oxy: jax.Array,            # i32 scalar: ox + oy (global checkerboard phase)
-    tile: Tuple[int, int] = (8, 128),
-    linf: bool = True,
-    interpret: bool = False,
-):
-    """Hybrid RB-GS sweep + pre-sweep residual partials in one grid pass.
+    def outside(gx, ref):
+        """Plane ``gx`` outside the slab: the block's own plane, the x∓
+        face halo one step past the edge (anything further is dead)."""
+        ghost = jnp.where(gx < 0, gxm_ref[0], gxp_ref[0])
+        return jnp.where((gx >= 0) & (gx < bx), ref[0], ghost)
 
-    Returns ``(new_block [bx,by,bz], residual partials [nx, ny])`` where the
-    partials reduce ``b − A x_in`` (the *input* state's residual — the free
-    by-product of the relaxation)."""
-    bx, by = b2.shape[0] - 2, b2.shape[1] - 2
-    bz = b2.shape[2]
-    tx, ty = min(tile[0], bx), min(tile[1], by)
-    assert bx % tx == 0 and by % ty == 0, (bx, by, tx, ty)
-    nx, ny = bx // tx, by // ty
-    coefs = stencil_coefs.astype(b2.dtype)
-    oxy_arr = jnp.asarray(oxy, jnp.int32).reshape((1,))
+    # input state of planes x0-2 … x0+tx+1
+    planes = ([outside(x0 - 2, xm2_ref), outside(x0 - 1, xm1_ref)]
+              + [x_ref[t] for t in range(tx)]
+              + [outside(x0 + tx, xp1_ref), outside(x0 + tx + 1, xp2_ref)])
+    yhalo = ([(gym_m_ref[0], gyp_m_ref[0], gzm_m_ref[0], gzp_m_ref[0])]
+             + [(gym_ref[t], gyp_ref[t], gzm_ref[t], gzp_ref[t])
+                for t in range(tx)]
+             + [(gym_p_ref[0], gyp_p_ref[0], gzm_p_ref[0], gzp_p_ref[0])])
+    bs = [bm_ref[0]] + [b_ref[t] for t in range(tx)] + [bp_ref[0]]
 
-    new, res = pl.pallas_call(
-        functools.partial(_rbgs_kernel, linf=linf, tx=tx, ty=ty, bx=bx, by=by),
-        grid=(nx, ny),
-        in_specs=[
-            pl.BlockSpec(memory_space=_ANY),       # ghosted field stays in HBM
-            pl.BlockSpec(memory_space=_ANY),       # padded rhs (windowed load)
-            pl.BlockSpec(memory_space=_ANY),       # 7 scalars
-            pl.BlockSpec(memory_space=_ANY),       # checkerboard phase
-        ],
-        out_specs=[
-            pl.BlockSpec((tx, ty, bz), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bx, by, bz), b2.dtype),
-            jax.ShapeDtypeStruct((nx, ny), jnp.float32),
-        ],
-        interpret=interpret,
-    )(g2, b2, coefs, oxy_arr)
-    return new, res
+    # colour 0 on planes x0-1 … x0+tx (index s = plane - x0 + 1), with the
+    # slab's residual from the same off-diagonal apply
+    upd0 = []
+    acc = None
+    for s in range(tx + 2):
+        c = planes[s + 1]
+        off = _plane_off(c, planes[s], planes[s + 2], *yhalo[s], k)
+        u = jnp.where(even(x0 + s - 1), (bs[s] - off) / diag, c)
+        if s == 0 or s == tx + 1:
+            gx = x0 + s - 1
+            u = jnp.where((gx >= 0) & (gx < bx), u, c)   # ghosts stay frozen
+        else:
+            acc = _accumulate(acc, bs[s] - (diag * c + off), linf)
+        upd0.append(u)
+
+    # colour 1 on the slab, against same-sweep colour-0 values
+    for t in range(tx):
+        s = t + 1
+        off1 = _plane_off(upd0[s], upd0[s - 1], upd0[s + 1], *yhalo[s], k)
+        new_ref[t] = jnp.where(even(x0 + t), upd0[s], (bs[s] - off1) / diag)
+    _write_partial(res_ref, acc, linf)
 
 
 # ---------------------------------------------------------------------------
-# Halo-consuming flavours: explicit face buffers for all partitioned faces
+# pallas_call wrappers
 # ---------------------------------------------------------------------------
-#
-# The multi-axis shard runtime exchanges up to six face planes (x/y/z may
-# all be partitioned) and hands them to the kernel as-is — no host-side
-# ghost assembly, no assumption that y/z are contiguous.  Each tile builds
-# its ghosted window in-register: the core tile plus thin clamped loads of
-# the neighbouring rows/columns of the *unghosted* block, with the halo
-# plane substituted wherever the window crosses the block boundary.
-# Diagonal window corners stay zero for the ±1 window (the 7-point star
-# never reads them); the ±2 RB-GS window picks its in-block corner cells
-# explicitly (they feed the ring's colour-0 recompute on interior tiles).
 
 
-def _pick_row(x_ref, hxm_ref, hxp_ref, q, y0, ny, bx, bz, dtype):
-    """(1, ny, bz) window row at global row ``q``, cols ``[y0, y0+ny)``:
-    an in-block row of x, the x∓ halo plane at q == -1/bx, zeros beyond."""
-    loaded = pl.load(x_ref, (pl.ds(jnp.clip(q, 0, bx - 1), 1),
-                             pl.ds(y0, ny), pl.ds(0, bz)))
-    hm = pl.load(hxm_ref, (pl.ds(y0, ny), pl.ds(0, bz)))[None]
-    hp = pl.load(hxp_ref, (pl.ds(y0, ny), pl.ds(0, bz)))[None]
-    v = jnp.where(q == -1, hm.astype(dtype),
-                  jnp.where(q == bx, hp.astype(dtype), loaded))
-    return jnp.where((q < -1) | (q > bx), jnp.zeros_like(v), v)
+def _face_specs(bx, by, bz, tx):
+    """Blocks of the six face planes, reshaped by ``_face_arrays``."""
+    return [
+        pl.BlockSpec((1, by, bz), lambda i: (0, 0, 0)),       # gxm
+        pl.BlockSpec((1, by, bz), lambda i: (0, 0, 0)),       # gxp
+        pl.BlockSpec((tx, 1, bz), lambda i: (i, 0, 0)),       # gym
+        pl.BlockSpec((tx, 1, bz), lambda i: (i, 0, 0)),       # gyp
+        pl.BlockSpec((tx, by, 1), lambda i: (i, 0, 0)),       # gzm
+        pl.BlockSpec((tx, by, 1), lambda i: (i, 0, 0)),       # gzp
+    ]
 
 
-def _pick_col(x_ref, hym_ref, hyp_ref, q, x0, nx, by, bz, dtype):
-    """(nx, 1, bz) window column at global col ``q``, rows ``[x0, x0+nx)``."""
-    loaded = pl.load(x_ref, (pl.ds(x0, nx),
-                             pl.ds(jnp.clip(q, 0, by - 1), 1), pl.ds(0, bz)))
-    hm = pl.load(hym_ref, (pl.ds(x0, nx), pl.ds(0, bz)))[:, None]
-    hp = pl.load(hyp_ref, (pl.ds(x0, nx), pl.ds(0, bz)))[:, None]
-    v = jnp.where(q == -1, hm.astype(dtype),
-                  jnp.where(q == by, hp.astype(dtype), loaded))
-    return jnp.where((q < -1) | (q > by), jnp.zeros_like(v), v)
+def _face_arrays(halos, x):
+    """The six face planes in block dtype, shaped so every slab takes a
+    tile-legal block: x faces ``(1, by, bz)``, y faces ``(bx, 1, bz)``,
+    z faces ``(bx, by, 1)``."""
+    bx, by, bz = x.shape
+    gxm, gxp, gym, gyp, gzm, gzp = (h.astype(x.dtype) for h in halos)
+    return (gxm.reshape(1, by, bz), gxp.reshape(1, by, bz),
+            gym.reshape(bx, 1, bz), gyp.reshape(bx, 1, bz),
+            gzm.reshape(bx, by, 1), gzp.reshape(bx, by, 1))
 
 
-def _pick_cell(x_ref, halo_refs, qx, qy, bx, by, bz, dtype):
-    """(1, 1, bz) window cell at global (qx, qy): in-block x, the face halo
-    when exactly one coordinate is a ghost, zero otherwise (both-ghost
-    diagonal cells are arithmetically dead in both kernels)."""
-    hxm_ref, hxp_ref, hym_ref, hyp_ref = halo_refs
-    loaded = pl.load(x_ref, (pl.ds(jnp.clip(qx, 0, bx - 1), 1),
-                             pl.ds(jnp.clip(qy, 0, by - 1), 1), pl.ds(0, bz)))
-    hxm = pl.load(hxm_ref, (pl.ds(jnp.clip(qy, 0, by - 1), 1),
-                            pl.ds(0, bz)))[None]
-    hxp = pl.load(hxp_ref, (pl.ds(jnp.clip(qy, 0, by - 1), 1),
-                            pl.ds(0, bz)))[None]
-    hym = pl.load(hym_ref, (pl.ds(jnp.clip(qx, 0, bx - 1), 1),
-                            pl.ds(0, bz)))[:, None]
-    hyp = pl.load(hyp_ref, (pl.ds(jnp.clip(qx, 0, bx - 1), 1),
-                            pl.ds(0, bz)))[:, None]
-    in_x = (qx >= 0) & (qx < bx)
-    in_y = (qy >= 0) & (qy < by)
-    v = jnp.where(in_x & in_y, loaded, jnp.zeros_like(loaded))
-    v = jnp.where((qx == -1) & in_y, hxm.astype(dtype), v)
-    v = jnp.where((qx == bx) & in_y, hxp.astype(dtype), v)
-    v = jnp.where((qy == -1) & in_x, hym.astype(dtype), v)
-    v = jnp.where((qy == by) & in_x, hyp.astype(dtype), v)
-    return v
+def _plane_spec(by, bz, index):
+    """One x-plane of a block, at a clamped plane index."""
+    return pl.BlockSpec((1, by, bz), lambda i: (index(i), 0, 0))
 
 
-def _pick_zplane(gz_ref, qx, nx, qy, ny, bx, by):
-    """(nx, ny) window of a z halo plane at rows/cols from (qx, qy); zeros
-    where the window leaves the block (ghost rows' z-corners are dead)."""
-    v = pl.load(gz_ref, (pl.ds(jnp.clip(qx, 0, bx - nx), nx),
-                         pl.ds(jnp.clip(qy, 0, by - ny), ny)))
-    ok = (qx >= 0) & (qx + nx <= bx) & (qy >= 0) & (qy + ny <= by)
-    return jnp.where(ok, v, jnp.zeros_like(v))
+def _partials(res):
+    return res[::_PART[0], 0]
 
 
-def _halo_window(x_ref, halo_refs, i, j, tx, ty, bx, by, bz, pad, dtype):
-    """Assemble the (tx+2·pad, ty+2·pad, bz+2) ghosted window of tile
-    (i, j) from the unghosted block + six face planes.  ``pad=1`` is the
-    Jacobi ±1 window; ``pad=2`` the RB-GS ±2 window (its outermost frame
-    carries real in-block values where they exist — interior tiles consume
-    them through the ring's colour-0 recompute — and dead zeros/halos at
-    the block edge, which the kernel's ``real`` mask freezes)."""
-    hxm, hxp, hym, hyp, hzm, hzp = halo_refs
-    x0, y0 = i * tx, j * ty
-
-    def zrow(qx, qy0, ny):
-        zm = _pick_zplane(hzm, qx, 1, qy0, ny, bx, by)[:, :, None]
-        zp = _pick_zplane(hzp, qx, 1, qy0, ny, bx, by)[:, :, None]
-        return zm.astype(dtype), zp.astype(dtype)
-
-    def row_slab(qx):
-        """(1, ty + 2·pad, bz + 2) full-width window row at global row qx."""
-        core = _pick_row(x_ref, hxm, hxp, qx, y0, ty, bx, bz, dtype)
-        zm, zp = zrow(qx, y0, ty)
-        parts = [jnp.concatenate([zm, core, zp], axis=2)]
-        for dq in range(1, pad + 1):
-            for side, qy in ((0, y0 - dq), (1, y0 + ty + dq - 1)):
-                cell = _pick_cell(x_ref, (hxm, hxp, hym, hyp), qx, qy,
-                                  bx, by, bz, dtype)
-                czm = _pick_zplane(hzm, qx, 1, qy, 1, bx, by)[:, :, None]
-                czp = _pick_zplane(hzp, qx, 1, qy, 1, bx, by)[:, :, None]
-                cz = jnp.concatenate([czm.astype(dtype), cell,
-                                      czp.astype(dtype)], axis=2)
-                parts = [cz] + parts if side == 0 else parts + [cz]
-        return jnp.concatenate(parts, axis=1)
-
-    # middle slab: the core tile, y-extended by pad picked columns per side
-    core = pl.load(x_ref, (pl.ds(x0, tx), pl.ds(y0, ty), pl.ds(0, bz)))
-    zm = _pick_zplane(hzm, x0, tx, y0, ty, bx, by)[:, :, None].astype(dtype)
-    zp = _pick_zplane(hzp, x0, tx, y0, ty, bx, by)[:, :, None].astype(dtype)
-    mid_parts = [jnp.concatenate([zm, core, zp], axis=2)]
-    for dq in range(1, pad + 1):
-        for side, qy in ((0, y0 - dq), (1, y0 + ty + dq - 1)):
-            col = _pick_col(x_ref, hym, hyp, qy, x0, tx, by, bz, dtype)
-            czm = _pick_zplane(hzm, x0, tx, qy, 1, bx, by)[:, :, None]
-            czp = _pick_zplane(hzp, x0, tx, qy, 1, bx, by)[:, :, None]
-            cz = jnp.concatenate([czm.astype(dtype), col,
-                                  czp.astype(dtype)], axis=2)
-            mid_parts = [cz] + mid_parts if side == 0 else mid_parts + [cz]
-    mid = jnp.concatenate(mid_parts, axis=1)
-
-    slabs = [mid]
-    for dq in range(1, pad + 1):
-        slabs = [row_slab(x0 - dq)] + slabs + [row_slab(x0 + tx + dq - 1)]
-    return jnp.concatenate(slabs, axis=0)
+def _params():
+    return pltpu.CompilerParams(dimension_semantics=("parallel",),
+                                vmem_limit_bytes=_VMEM_LIMIT)
 
 
-def _halo_kernel(x_ref, hxm, hxp, hym, hyp, hzm, hzp, b_ref, coef_ref,
-                 new_ref, res_ref, *, op: str, linf: bool, tx: int, ty: int,
-                 bx: int, by: int):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    bz = x_ref.shape[2]
-    g = _halo_window(x_ref, (hxm, hxp, hym, hyp, hzm, hzp), i, j, tx, ty,
-                     bx, by, bz, pad=1, dtype=x_ref.dtype)
-    b = b_ref[...]
-    c = coef_ref[...]
-    diag, xm, xp, ym, yp, zm, zp = c[0], c[1], c[2], c[3], c[4], c[5], c[6]
-    off = _stencil_off(g, xm, xp, ym, yp, zm, zp)
-    r = b - (diag * g[1:-1, 1:-1, 1:-1] + off)
-    if op == "sweep":
-        new_ref[...] = (b - off) / diag
-    else:
-        new_ref[...] = g[1:-1, 1:-1, 1:-1]
-    if linf:
-        res_ref[0, 0] = jnp.max(jnp.abs(r)).astype(jnp.float32)
-    else:
-        res_ref[0, 0] = jnp.sum((r * r).astype(jnp.float32))
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
-def _rbgs_halo_kernel(x_ref, hxm, hxp, hym, hyp, hzm, hzp, b2_ref, coef_ref,
-                      oxyz_ref, new_ref, res_ref, *, linf: bool, tx: int,
-                      ty: int, bx: int, by: int):
-    """The ±2-window hybrid RB-GS sweep over an unghosted block + six face
-    buffers — the same single-pass recompute scheme as ``_rbgs_kernel``,
-    with the window assembled in-register instead of pre-ghosted."""
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    bz = x_ref.shape[2]
-    w = _halo_window(x_ref, (hxm, hxp, hym, hyp, hzm, hzp), i, j, tx, ty,
-                     bx, by, bz, pad=2, dtype=x_ref.dtype)
-    bw = pl.load(b2_ref, (pl.ds(i * tx, tx + 2), pl.ds(j * ty, ty + 2),
-                          pl.ds(0, bz)))
-    c = coef_ref[...]
-    diag, xm, xp, ym, yp, zm, zp = c[0], c[1], c[2], c[3], c[4], c[5], c[6]
-    off_w = _stencil_off(w, xm, xp, ym, yp, zm, zp)    # (tx+2, ty+2, bz)
-    x_w = w[1:-1, 1:-1, 1:-1]
-    shp = (tx + 2, ty + 2, bz)
-    gx = jax.lax.broadcasted_iota(jnp.int32, shp, 0) + i * tx - 1
-    gy = jax.lax.broadcasted_iota(jnp.int32, shp, 1) + j * ty - 1
-    gz = jax.lax.broadcasted_iota(jnp.int32, shp, 2)
-    parity = jnp.mod(gx + gy + gz + oxyz_ref[0], 2)
-    real = (gx >= 0) & (gx < bx) & (gy >= 0) & (gy < by)
-    upd0 = jnp.where((parity == 0) & real, (bw - off_w) / diag, x_w)
-    w1 = w.at[1:-1, 1:-1, 1:-1].set(upd0)
-    off1 = _stencil_off(w1, xm, xp, ym, yp, zm, zp)[1:-1, 1:-1, :]
-    b_t = bw[1:-1, 1:-1, :]
-    new1 = (b_t - off1) / diag
-    new_ref[...] = jnp.where(parity[1:-1, 1:-1, :] == 1, new1,
-                             upd0[1:-1, 1:-1, :])
-    r = b_t - (diag * x_w[1:-1, 1:-1, :] + off_w[1:-1, 1:-1, :])
-    if linf:
-        res_ref[0, 0] = jnp.max(jnp.abs(r)).astype(jnp.float32)
-    else:
-        res_ref[0, 0] = jnp.sum((r * r).astype(jnp.float32))
-
-
-def _halo6(halos, b_like):
-    """Normalise the six face planes to the block dtype (zero planes for
-    unpartitioned/boundary faces are the caller's contract)."""
-    gxm, gxp, gym, gyp, gzm, gzp = halos
-    return tuple(h.astype(b_like.dtype) for h in
-                 (gxm, gxp, gym, gyp, gzm, gzp))
-
-
-@functools.partial(jax.jit, static_argnames=("tile", "op", "linf", "interpret"))
+@functools.partial(jax.jit, static_argnames=("op", "linf", "interpret"))
 def fused_sweep_residual_halo(
     x: jax.Array,              # [bx, by, bz] unghosted block
     halos,                     # 6 face planes (gxm, gxp, gym, gyp, gzm, gzp)
     b: jax.Array,              # [bx, by, bz]
     stencil_coefs: jax.Array,  # [7] (diag, xm, xp, ym, yp, zm, zp)
-    tile: Tuple[int, int] = (8, 128),
     op: str = "sweep",
     linf: bool = True,
     interpret: bool = False,
 ):
-    """Jacobi sweep + input-state residual partials from an unghosted block
-    and explicit halo buffers for every partitioned face — no host-side
-    ghost assembly (one fewer HBM materialisation of the (bx+2)³ array).
+    """Jacobi sweep (``op="sweep"``) or residual-only pass (``"residual"``,
+    the block comes back unchanged) + input-state residual partials, from
+    an unghosted block and explicit halo planes for every face — no
+    host-side ghost assembly.
 
-    Returns ``(new_block [bx,by,bz], residual partials [nx, ny])``."""
-    bx, by, bz = b.shape
-    tx, ty = min(tile[0], bx), min(tile[1], by)
-    assert bx % tx == 0 and by % ty == 0, (bx, by, tx, ty)
-    nx, ny = bx // tx, by // ty
-    coefs = stencil_coefs.astype(b.dtype)
-    faces = _halo6(halos, b)
-
-    new, res = pl.pallas_call(
-        functools.partial(_halo_kernel, op=op, linf=linf, tx=tx, ty=ty,
-                          bx=bx, by=by),
-        grid=(nx, ny),
-        in_specs=[pl.BlockSpec(memory_space=_ANY)] * 7 + [
-            pl.BlockSpec((tx, ty, bz), lambda i, j: (i, j, 0)),
-            pl.BlockSpec(memory_space=_ANY),       # 7 scalars
+    Returns ``(new_block [bx,by,bz], residual partials [nx])`` (one partial
+    per x-slab: max|r| for l∞, Σr² for l2, in f32)."""
+    bx, by, bz = x.shape
+    tx = _slab_planes(bx, by, bz, x.dtype.itemsize)
+    nx = bx // tx
+    sweep = op == "sweep"
+    slab = pl.BlockSpec((tx, by, bz), lambda i: (i, 0, 0))
+    part = pl.BlockSpec(_PART, lambda i: (i, 0))
+    part_shape = jax.ShapeDtypeStruct((nx * _PART[0], _PART[1]), jnp.float32)
+    outs = pl.pallas_call(
+        functools.partial(_jacobi_kernel, sweep=sweep, linf=linf, nx=nx),
+        grid=(nx,),
+        in_specs=[
+            _SMEM,
+            slab,
+            _plane_spec(by, bz, lambda i: jnp.maximum(i * tx - 1, 0)),
+            _plane_spec(by, bz, lambda i: jnp.minimum((i + 1) * tx, bx - 1)),
+            *_face_specs(bx, by, bz, tx),
+            slab,
         ],
-        out_specs=[
-            pl.BlockSpec((tx, ty, bz), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bx, by, bz), b.dtype),
-            jax.ShapeDtypeStruct((nx, ny), jnp.float32),
-        ],
+        out_specs=[slab, part] if sweep else [part],
+        out_shape=([jax.ShapeDtypeStruct(x.shape, x.dtype)] if sweep else [])
+        + [part_shape],
+        compiler_params=_params(),
         interpret=interpret,
-    )(x, *faces, b, coefs)
-    return new, res
+    )(stencil_coefs.astype(x.dtype), x, x, x, *_face_arrays(halos, x),
+      b.astype(x.dtype))
+    return (outs[0] if sweep else x), _partials(outs[-1])
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "linf", "interpret"))
+@functools.partial(jax.jit, static_argnames=("linf", "interpret"))
 def fused_rbgs_sweep_residual_halo(
     x: jax.Array,              # [bx, by, bz] unghosted block
     halos,                     # 6 face planes (gxm, gxp, gym, gyp, gzm, gzp)
     b: jax.Array,              # [bx, by, bz]
     stencil_coefs: jax.Array,  # [7] (diag, xm, xp, ym, yp, zm, zp)
     oxyz: jax.Array,           # i32 scalar: ox + oy + oz (checkerboard phase)
-    tile: Tuple[int, int] = (8, 128),
     linf: bool = True,
     interpret: bool = False,
 ):
     """Hybrid RB-GS sweep + pre-sweep residual partials from an unghosted
-    block and explicit halo buffers (the halo-consuming twin of
-    ``fused_rbgs_sweep_residual``)."""
-    bx, by, bz = b.shape
-    tx, ty = min(tile[0], bx), min(tile[1], by)
-    assert bx % tx == 0 and by % ty == 0, (bx, by, tx, ty)
-    nx, ny = bx // tx, by // ty
-    coefs = stencil_coefs.astype(b.dtype)
-    faces = _halo6(halos, b)
-    b2 = jnp.pad(b, ((1, 1), (1, 1), (0, 0)))
-    oxyz_arr = jnp.asarray(oxyz, jnp.int32).reshape((1,))
+    block and explicit halo planes, in one grid pass.
 
+    Returns ``(new_block [bx,by,bz], residual partials [nx])`` where the
+    partials reduce ``b − A x_in`` (the *input* state's residual — the free
+    by-product of the relaxation)."""
+    bx, by, bz = x.shape
+    tx = _slab_planes(bx, by, bz, x.dtype.itemsize)
+    nx = bx // tx
+
+    def plane(offset):
+        return _plane_spec(
+            by, bz, lambda i: jnp.clip(i * tx + offset, 0, bx - 1))
+
+    def row(shape, offset):
+        return pl.BlockSpec(
+            shape, lambda i: (jnp.clip(i * tx + offset, 0, bx - 1), 0, 0))
+
+    faces = _face_arrays(halos, x)
+    fx, fy, fz = faces[:2], faces[2:4], faces[4:]
+    specs = _face_specs(bx, by, bz, tx)
+    slab = pl.BlockSpec((tx, by, bz), lambda i: (i, 0, 0))
+    yrow, zcol = (1, 1, bz), (1, by, 1)
     new, res = pl.pallas_call(
-        functools.partial(_rbgs_halo_kernel, linf=linf, tx=tx, ty=ty,
-                          bx=bx, by=by),
-        grid=(nx, ny),
-        in_specs=[pl.BlockSpec(memory_space=_ANY)] * 10,
-        out_specs=[
-            pl.BlockSpec((tx, ty, bz), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
+        functools.partial(_rbgs_kernel, linf=linf, bx=bx),
+        grid=(nx,),
+        in_specs=[
+            _SMEM, _SMEM,
+            slab, plane(-2), plane(-1), plane(tx), plane(tx + 1),
+            *specs[:2],
+            specs[2], row(yrow, -1), row(yrow, tx),
+            specs[3], row(yrow, -1), row(yrow, tx),
+            specs[4], row(zcol, -1), row(zcol, tx),
+            specs[5], row(zcol, -1), row(zcol, tx),
+            slab, plane(-1), plane(tx),
         ],
+        out_specs=[slab, pl.BlockSpec(_PART, lambda i: (i, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((bx, by, bz), b.dtype),
-            jax.ShapeDtypeStruct((nx, ny), jnp.float32),
+            jax.ShapeDtypeStruct(x.shape, x.dtype),
+            jax.ShapeDtypeStruct((nx * _PART[0], _PART[1]), jnp.float32),
         ],
+        compiler_params=_params(),
         interpret=interpret,
-    )(x, *faces, b2, coefs, oxyz_arr)
-    return new, res
+    )(stencil_coefs.astype(x.dtype),
+      jnp.asarray(oxyz, jnp.int32).reshape((1,)),
+      x, x, x, x, x, *fx,
+      fy[0], fy[0], fy[0], fy[1], fy[1], fy[1],
+      fz[0], fz[0], fz[0], fz[1], fz[1], fz[1],
+      *(b.astype(x.dtype),) * 3)
+    return new, _partials(res)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "op", "linf", "interpret"))
+# ---------------------------------------------------------------------------
+# Ghosted-layout entries
+# ---------------------------------------------------------------------------
+
+
+def _unghost(g, pad_xy: int):
+    """Interior + six face planes of a block ghosted by ``pad_xy`` layers in
+    x/y (ghosts on the innermost layer) and one layer in z."""
+    p = pad_xy
+    x = g[p:-p, p:-p, 1:-1]
+    q = p - 1
+    halos = (g[q, p:-p, 1:-1], g[-p, p:-p, 1:-1],
+             g[p:-p, q, 1:-1], g[p:-p, -p, 1:-1],
+             g[p:-p, p:-p, 0], g[p:-p, p:-p, -1])
+    return x, halos
+
+
+@functools.partial(jax.jit, static_argnames=("op", "linf", "interpret"))
 def fused_sweep_residual(
     g: jax.Array,              # [(bx+2), (by+2), (bz+2)] ghosted block
     b: jax.Array,              # [bx, by, bz]
     stencil_coefs: jax.Array,  # [7] (diag, xm, xp, ym, yp, zm, zp)
-    tile: Tuple[int, int] = (8, 128),
     op: str = "sweep",
     linf: bool = True,
     interpret: bool = False,
 ):
-    """Returns (new_block [bx,by,bz], residual partials [nx, ny])."""
-    bx, by, bz = b.shape
-    tx, ty = min(tile[0], bx), min(tile[1], by)
-    assert bx % tx == 0 and by % ty == 0, (bx, by, tx, ty)
-    nx, ny = bx // tx, by // ty
-    coefs = stencil_coefs.astype(b.dtype)
+    """Jacobi sweep / residual pass over a ±1 ghosted block (corners
+    unused).  Returns ``(new_block [bx,by,bz], residual partials [nx])``."""
+    x, halos = _unghost(g, 1)
+    return fused_sweep_residual_halo(x, halos, b, stencil_coefs, op=op,
+                                     linf=linf, interpret=interpret)
 
-    new, res = pl.pallas_call(
-        functools.partial(_kernel, op=op, linf=linf, tx=tx, ty=ty),
-        grid=(nx, ny),
-        in_specs=[
-            pl.BlockSpec(memory_space=_ANY),       # ghosted field stays in HBM
-            pl.BlockSpec((tx, ty, bz), lambda i, j: (i, j, 0)),
-            pl.BlockSpec(memory_space=_ANY),       # 7 scalars
-        ],
-        out_specs=[
-            pl.BlockSpec((tx, ty, bz), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bx, by, bz), b.dtype),
-            jax.ShapeDtypeStruct((nx, ny), jnp.float32),
-        ],
-        interpret=interpret,
-    )(g, b, coefs)
-    return new, res
+
+@functools.partial(jax.jit, static_argnames=("linf", "interpret"))
+def fused_rbgs_sweep_residual(
+    g2: jax.Array,             # [(bx+4), (by+4), (bz+2)] twice-padded block
+    b2: jax.Array,             # [bx+2, by+2, bz] rhs, zero-padded ±1 in x/y
+    stencil_coefs: jax.Array,  # [7] (diag, xm, xp, ym, yp, zm, zp)
+    oxy: jax.Array,            # i32 scalar: ox + oy (global checkerboard phase)
+    linf: bool = True,
+    interpret: bool = False,
+):
+    """Hybrid RB-GS sweep + pre-sweep residual partials over the twice-padded
+    layout of ``ops.ghost_pad2`` (ghosts one ring in; the outermost ring and
+    the rhs padding are never read).  Returns ``(new_block, partials [nx])``."""
+    x, halos = _unghost(g2, 2)
+    return fused_rbgs_sweep_residual_halo(x, halos, b2[1:-1, 1:-1, :],
+                                          stencil_coefs, oxy, linf=linf,
+                                          interpret=interpret)

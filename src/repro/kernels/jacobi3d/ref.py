@@ -1,21 +1,20 @@
 """Pure-jnp oracle for the jacobi3d kernel."""
 from __future__ import annotations
 
-from typing import Tuple
-
 import jax.numpy as jnp
 
 
-def residual_partials(r, tile: Tuple[int, int] = (8, 128), linf: bool = True):
-    """Per-(x,y)-tile residual partials of a residual block, mirroring the
-    kernel's [nx, ny] output layout."""
-    bx, by, _ = r.shape
-    tx, ty = min(tile[0], bx), min(tile[1], by)
-    nx, ny = bx // tx, by // ty
-    rt = r.reshape(nx, tx, ny, ty, -1)
+def contribution(r, linf: bool = True):
+    """Pre-σ local contribution of a residual block, in f32: ``max|r|``
+    (l∞) or ``Σr²`` (l2) — what the kernel's per-slab partials reduce to."""
     if linf:
-        return jnp.max(jnp.abs(rt), axis=(1, 3, 4)).astype(jnp.float32)
-    return jnp.sum((rt * rt).astype(jnp.float32), axis=(1, 3, 4))
+        return jnp.max(jnp.abs(r)).astype(jnp.float32)
+    return jnp.sum((r * r).astype(jnp.float32))
+
+
+def reduce_partials(parts, linf: bool = True):
+    """Combine a kernel's per-slab partials into the block contribution."""
+    return jnp.max(parts) if linf else jnp.sum(parts)
 
 
 def ghosted6_ref(x, halos):
@@ -34,16 +33,17 @@ def ghosted6_ref(x, halos):
     return g
 
 
-def fused_sweep_residual_halo_ref(x, halos, b, coefs,
-                                  tile: Tuple[int, int] = (8, 128),
-                                  op: str = "sweep", linf: bool = True):
+def fused_sweep_residual_halo_ref(x, halos, b, coefs, op: str = "sweep",
+                                  linf: bool = True):
     """Oracle for ``fused_sweep_residual_halo`` (assemble-then-sweep)."""
-    return fused_sweep_residual_ref(ghosted6_ref(x, halos), b, coefs,
-                                    tile=tile, op=op, linf=linf)
+    return fused_sweep_residual_ref(ghosted6_ref(x, halos), b, coefs, op=op,
+                                    linf=linf)
 
 
-def fused_sweep_residual_ref(g, b, coefs, tile: Tuple[int, int] = (8, 128),
-                             op: str = "sweep", linf: bool = True):
+def fused_sweep_residual_ref(g, b, coefs, op: str = "sweep",
+                             linf: bool = True):
+    """``(new_block, contribution)`` of a Jacobi sweep (or residual-only
+    pass) over a ±1 ghosted block."""
     diag, xm, xp, ym, yp, zm, zp = [coefs[i] for i in range(7)]
     off = (
         xm * g[:-2, 1:-1, 1:-1]
@@ -55,4 +55,4 @@ def fused_sweep_residual_ref(g, b, coefs, tile: Tuple[int, int] = (8, 128),
     )
     r = b - (diag * g[1:-1, 1:-1, 1:-1] + off)
     new = (b - off) / diag if op == "sweep" else g[1:-1, 1:-1, 1:-1]
-    return new, residual_partials(r, tile=tile, linf=linf)
+    return new, contribution(r, linf)

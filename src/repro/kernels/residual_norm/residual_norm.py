@@ -5,6 +5,11 @@ every outer iteration.  Unfused XLA does subtract → abs/square → reduce as
 separate HBM passes at production sizes; this kernel streams both operands
 through VMEM tiles once and emits per-tile partials (σ is applied by the
 wrapper / the mesh reduction).
+
+Layout: the flattened operands are viewed as lane-dense ``(rows, 128)``
+slabs; each grid step reduces ``block // 128`` rows and writes its partial
+into its own ``(8, 128)`` output tile (the smallest f32 block the TPU
+tiling accepts).
 """
 from __future__ import annotations
 
@@ -13,6 +18,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+_LANES = 128
+_PART = (8, _LANES)
 
 
 def _kernel(a_ref, b_ref, out_ref, *, linf: bool):
@@ -24,10 +32,8 @@ def _kernel(a_ref, b_ref, out_ref, *, linf: bool):
     # ~1e-7 · diag⁻¹ relative to the state
     ct = jnp.promote_types(a_ref.dtype, jnp.float32)
     d = (a_ref[...].astype(ct) - b_ref[...].astype(ct)).astype(jnp.float32)
-    if linf:
-        out_ref[0] = jnp.max(jnp.abs(d))
-    else:
-        out_ref[0] = jnp.sum(d * d)
+    part = jnp.max(jnp.abs(d)) if linf else jnp.sum(d * d)
+    out_ref[...] = jnp.full(out_ref.shape, part, jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "linf", "interpret"))
@@ -38,24 +44,27 @@ def diff_norm_partials(
     linf: bool = True,
     interpret: bool = False,
 ):
-    """Flattens inputs, returns per-block partials [nblocks] (f32)."""
+    """Flattens inputs, returns per-block partials [nblocks] (f32).
+
+    ``block`` (elements per partial) is rounded up to whole 128-lane rows
+    and capped at the padded input size."""
     af = a.reshape(-1)
     bf = b.reshape(-1)
     n = af.shape[0]
-    block = min(block, n)
+    rows = -(-min(block, n) // _LANES)
+    block = rows * _LANES
     pad = (-n) % block
     if pad:
         af = jnp.pad(af, (0, pad))
         bf = jnp.pad(bf, (0, pad))  # equal padding → zero diff
     nblk = af.shape[0] // block
-    return pl.pallas_call(
+    spec = pl.BlockSpec((rows, _LANES), lambda i: (i, 0))
+    out = pl.pallas_call(
         functools.partial(_kernel, linf=linf),
         grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((1,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((nblk,), jnp.float32),
+        in_specs=[spec, spec],
+        out_specs=pl.BlockSpec(_PART, lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((nblk * _PART[0], _LANES), jnp.float32),
         interpret=interpret,
-    )(af, bf)
+    )(af.reshape(-1, _LANES), bf.reshape(-1, _LANES))
+    return out[::_PART[0], 0]

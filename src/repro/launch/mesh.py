@@ -11,22 +11,29 @@ from typing import Optional, Tuple, Union
 
 import jax
 import numpy as np
+from jax.experimental import mesh_utils
 
-from repro.core.compat import make_mesh_compat as compat_make_mesh  # re-export
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` over every device with ``Auto`` axis types: the
+    shardings here are explicit ``PartitionSpec``s the compiler resolves
+    (``jax.make_mesh`` now defaults to ``Explicit``)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single-pod (256 chips) or 2×16×16 two-pod (512 chips) mesh."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model_axis: int = 1):
     """Tiny mesh over the locally-available devices (tests / examples)."""
     n = len(jax.devices())
     data = n // model_axis
-    return compat_make_mesh((data, model_axis), ("data", "model"))
+    return make_mesh((data, model_axis), ("data", "model"))
 
 
 def shard_axis_names(axis: str, ndim: int) -> Tuple[str, ...]:
@@ -51,7 +58,11 @@ def make_shard_mesh(n_shards: Optional[Union[int, Tuple[int, ...]]] = None,
     Unlike the production meshes this may use a *prefix* of the available
     devices (a 2-shard runtime on a 4-device host is a valid experiment),
     so it builds ``jax.sharding.Mesh`` directly instead of going through
-    ``make_mesh`` — which binds every device.
+    ``make_mesh`` — which binds every device.  A 1-D mesh over all devices
+    takes ``mesh_utils.create_device_mesh``'s ring order, which follows the
+    physical links: on a 2x2 TPU host the ring 0-1-3-2 steps only between
+    linked chips, where id order would make two hops diagonal.  Multi-axis
+    meshes keep id order, which on that host is the physical 2x2 grid.
     """
     devices = jax.devices()
     if n_shards is None:
@@ -72,7 +83,11 @@ def make_shard_mesh(n_shards: Optional[Union[int, Tuple[int, ...]]] = None,
             "platform_device_count before the first jax import to emulate "
             "more)")
     names = shard_axis_names(axis, len(shape))
-    return jax.sharding.Mesh(np.asarray(devices[:n]).reshape(shape), names)
+    if len(shape) == 1 and n == len(devices):
+        grid = mesh_utils.create_device_mesh(shape, devices)
+    else:
+        grid = np.asarray(devices[:n]).reshape(shape)
+    return jax.sharding.Mesh(grid, names)
 
 
 def shard_axis_of(mesh) -> str:

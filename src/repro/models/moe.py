@@ -238,10 +238,9 @@ def moe_apply(
         moe_block_local, plan=plan, gated=gated,
         model_axis=model_axis, fsdp_axis=fsdp_axis,
     )
-    from repro.core.compat import shard_map_compat
-
-    return shard_map_compat(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(x_spec, w_specs),
         out_specs=(x_spec, P()),
+        check_vma=False,
     )(x, weights)

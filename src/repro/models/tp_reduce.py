@@ -31,11 +31,10 @@ def tp_matmul_psum(
         partial_out = jnp.einsum("bsf,fd->bsd", h_blk, w_blk)
         return jax.lax.psum(partial_out.astype(jnp.bfloat16), model_axis)
 
-    from repro.core.compat import shard_map_compat
-
-    return shard_map_compat(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(dp_axes, None, model_axis), P(model_axis, None)),
         out_specs=P(dp_axes, None, None),
+        check_vma=False,
     )(h, w.astype(jnp.bfloat16))

@@ -46,6 +46,7 @@ result; ``tests/test_shard_runtime.py`` holds the parity proofs.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 from typing import Callable, NamedTuple, Optional, Sequence, Tuple, Union
 
@@ -55,16 +56,17 @@ import numpy as np
 
 from repro.core import detection
 from repro.core import residual as res
-from repro.core.compat import shard_map_compat as _shard_map
 from repro.core.reduction import REDUCTIONS, get_reduction
 from repro.kernels.jacobi3d import ops as jac_ops
 from repro.kernels.residual_norm import ops as rn_ops
 from repro.solvers import gauss_seidel, jacobi
 from repro.solvers.convdiff import Stencil
-from repro.solvers.fixed_point import _shift, ghosted, ghosted6
+from repro.solvers.fixed_point import _shift, ghosted
 from repro.solvers.partition import MeshPartition
 
 P = jax.sharding.PartitionSpec
+# replication checking off: per-shard state (rings, lanes) varies by design
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 # REDUCTIONS is re-exported above from repro.core.reduction — the registry is
 # the single source of truth; historical importers of
@@ -465,8 +467,8 @@ def _make_convdiff_mesh_runtime(cfg: ShardRuntimeConfig, mesh, stencil:
 
     def _face_sweep(x, h6, b, d, last):
         """The new values of one face of the block, as the full Jacobi sweep
-        will produce them, from a thickness-1 slab: same stencil inputs in
-        the same operation order, so the result is bitwise-identical to the
+        will produce them, from a thickness-1 slab: the same sweep entry on
+        the same stencil inputs, so the result is bitwise-identical to the
         corresponding face of ``sweep(x, ...)`` — cheap enough to compute
         *before* the full sweep and hand to the exchange."""
         idx = x.shape[d] - 1 if last else 0
@@ -489,7 +491,7 @@ def _make_convdiff_mesh_runtime(cfg: ShardRuntimeConfig, mesh, stencil:
                 gp = jax.lax.slice_in_dim(h6[2 * e + 1], idx, idx + 1,
                                           axis=pos)
             sg.extend((gm, gp))
-        new_slab = jacobi.jacobi_sweep(st, ghosted6(slab, tuple(sg)), b_slab)
+        new_slab = jac_ops.sweep_halo(st, slab, tuple(sg), b_slab)
         return jnp.squeeze(new_slab, axis=d)
 
     def fused_step(x, faces, b):
@@ -625,7 +627,10 @@ def make_pagerank_runtime(cfg: ShardRuntimeConfig, mesh, n: int,
                                             (start,))
 
     def sweep(x, view, P_rows):
-        return d * (P_rows @ _own_current(x, view)) + v
+        # full-precision f32 products: a TPU's default f32 matmul rounds to
+        # bf16 and would converge to another operator's fixed point
+        return d * jnp.matmul(P_rows, _own_current(x, view),
+                              precision="highest") + v
 
     def sweep_contrib(x, view, P_rows):
         new = sweep(x, view, P_rows)
@@ -727,9 +732,12 @@ def pagerank_reference_trace(P_dense: jax.Array, n: int, steps: int,
     v = (1.0 - d) / n
     x = jnp.full((n,), 1.0 / n, P_dense.dtype)
 
+    def f(x):
+        return d * jnp.matmul(P_dense, x, precision="highest") + v
+
     def step(x, _):
-        x = d * (P_dense @ x) + v
-        r = res.local_contribution(d * (P_dense @ x) + v - x, ord)
+        x = f(x)
+        r = res.local_contribution(f(x) - x, ord)
         return x, res.sigma(r, ord).astype(jnp.float32)
 
     _, trace = jax.lax.scan(step, x, None, length=steps)

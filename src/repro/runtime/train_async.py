@@ -49,7 +49,6 @@ import numpy as np
 
 from repro.core import detection
 from repro.core import residual as res
-from repro.core.compat import shard_map_compat as _shard_map
 from repro.core.reduction import get_reduction
 from repro.runtime.shard_runtime import (
     _butterfly_rounds,
@@ -303,9 +302,9 @@ def make_train_runtime(problem: MLFixedPointProblem, cfg: TrainAsyncConfig,
         x=row_spec, residual=P(), rounds=P(), converged=P(),
         local_steps=P(axis), verifications=P(), loss=P(), trace=P(),
     )
-    return _shard_map(loop, mesh=mesh,
-                      in_specs=(row_spec, row_spec, P(axis)),
-                      out_specs=out_specs)
+    return jax.shard_map(loop, mesh=mesh,
+                         in_specs=(row_spec, row_spec, P(axis)),
+                         out_specs=out_specs, check_vma=False)
 
 
 def init_replicas(problem: MLFixedPointProblem, p: int) -> np.ndarray:

@@ -40,7 +40,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import detection
 from repro.core import residual as res
-from repro.core.compat import axis_size_compat, shard_map_compat as _shard_map
 from repro.solvers import gauss_seidel, jacobi
 from repro.solvers.convdiff import Stencil
 
@@ -248,11 +247,12 @@ def make_sharded_solver(cfg: SolverConfig, mesh: Mesh, ax_x: str = "data", ax_y:
         )
 
     spec = P(ax_x, ax_y, None)
-    return _shard_map(
+    return jax.shard_map(
         local_solve,
         mesh=mesh,
         in_specs=(spec, spec),
         out_specs=SolveResult(x=spec, residual=P(), outer_iters=P(), converged=P()),
+        check_vma=False,
     )
 
 
@@ -260,7 +260,7 @@ def _linear_index(axis_names: Tuple[str, ...]):
     """Linear rank along possibly-composite mesh axes."""
     idx = jnp.zeros((), jnp.int32)
     for a in axis_names:
-        idx = idx * axis_size_compat(a) + jax.lax.axis_index(a)
+        idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
     return idx
 
 

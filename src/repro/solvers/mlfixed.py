@@ -242,20 +242,24 @@ class MLFixedPointProblem:
 
         g = jnp.asarray(self.gamma if gamma is None else gamma)
         g = g[..., None] if g.ndim else g
+        # f32 products in full precision (a TPU's default rounds to bf16)
+        hi = "highest"
         if self.task == "lstsq":
             H = jnp.asarray(self.H if H is None else H)
             c = jnp.asarray(self.c if c is None else c)
-            G = (X @ H.T if H.ndim == 2
-                 else jnp.einsum("bij,bj->bi", H, X)) - c
+            G = (jnp.matmul(X, H.T, precision=hi) if H.ndim == 2
+                 else jnp.einsum("bij,bj->bi", H, X, precision=hi)) - c
         else:
             A = jnp.asarray(self.A if A is None else A)
             s = jnp.asarray(self.s if s is None else s)
             import jax.nn
 
-            Z = X @ A.T if A.ndim == 2 else jnp.einsum("bmn,bn->bm", A, X)
+            Z = (jnp.matmul(X, A.T, precision=hi) if A.ndim == 2
+                 else jnp.einsum("bmn,bn->bm", A, X, precision=hi))
             W = -s * jax.nn.sigmoid(-s * Z)
-            G = ((W @ A) / self.m if A.ndim == 2
-                 else jnp.einsum("bm,bmn->bn", W, A) / self.m) + self.l2 * X
+            G = ((jnp.matmul(W, A, precision=hi) / self.m if A.ndim == 2
+                  else jnp.einsum("bm,bmn->bn", W, A, precision=hi) / self.m)
+                 + self.l2 * X)
         R = -g * G
         Y = X + R
         if np.isinf(self.ord):

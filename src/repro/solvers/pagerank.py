@@ -235,10 +235,13 @@ class PageRankProblem:
         import jax.numpy as jnp
 
         P = jnp.asarray(self.to_dense() if P is None else P)
+        # f32 products in full precision: a TPU's default f32 matmul rounds
+        # its inputs to bf16, which moves the fixed point by ~1e-3
         if P.ndim == 2:
-            Y = self.d * (X @ P.T) + self.v
+            Y = self.d * jnp.matmul(X, P.T, precision="highest") + self.v
         else:
-            Y = self.d * jnp.einsum("bij,bj->bi", P, X) + self.v
+            Y = self.d * jnp.einsum("bij,bj->bi", P, X,
+                                    precision="highest") + self.v
         R = Y - X
         if np.isinf(self.ord):
             contrib = jnp.max(jnp.abs(R), axis=1)

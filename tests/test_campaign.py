@@ -215,6 +215,28 @@ def test_failing_cell_aborts_with_spec_named(tmp_path):
         CELL_KINDS.pop("t_boom", None)
 
 
+def test_device_cells_run_inline_on_a_tpu(tmp_path, monkeypatch):
+    """One process per chip: cells that touch JAX never go to pool workers
+    when the backend is a TPU, whatever executor was asked for."""
+    import jax
+
+    @cell_kind("t_device", env=("jax",))
+    def _t_device(payload):
+        CALLS.append(("t_device", payload))
+        return {"payload": payload}
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    CALLS.clear()
+    try:
+        out = campaign.run_campaign(
+            [{"kind": "t_device", "payload": i} for i in range(3)],
+            _cfg(tmp_path, executor="process", workers=2), fingerprint="fp")
+    finally:
+        CELL_KINDS.pop("t_device", None)
+    assert out.executor == "inline" and out.workers == 0
+    assert sorted(CALLS) == [("t_device", i) for i in range(3)]
+
+
 # ---------------------------------------------------------------------------
 # process pool (real fork workers, real cell kind)
 # ---------------------------------------------------------------------------

@@ -138,40 +138,52 @@ def _halo_setup(bx=8, by=8, bz=8, dtype=jnp.float64):
     return st, coefs, x, b, halos
 
 
-@pytest.mark.parametrize("tile", [(4, 4), (8, 8), (4, 8)])
-@pytest.mark.parametrize("op", ["sweep", "residual"])
-def test_halo_kernel_matches_oracle(tile, op):
-    from repro.kernels.jacobi3d.jacobi3d import fused_sweep_residual_halo
-    from repro.kernels.jacobi3d.ref import fused_sweep_residual_halo_ref
+#: block shapes: one x-slab, two slabs, and one-plane slabs (a 512 KiB f64
+#: plane) whose ±2 window reaches the far face halo
+HALO_BLOCKS = [(8, 8, 8), (16, 8, 8), (3, 256, 256)]
 
-    _, coefs, x, b, halos = _halo_setup()
+
+@pytest.mark.parametrize("block", HALO_BLOCKS)
+@pytest.mark.parametrize("op", ["sweep", "residual"])
+def test_halo_kernel_matches_oracle(block, op):
+    from repro.kernels.jacobi3d.jacobi3d import fused_sweep_residual_halo
+    from repro.kernels.jacobi3d.ref import (
+        fused_sweep_residual_halo_ref,
+        reduce_partials,
+    )
+
+    _, coefs, x, b, halos = _halo_setup(*block)
     new_k, parts_k = fused_sweep_residual_halo(
-        x, halos, b, coefs, tile=tile, op=op, linf=True, interpret=True)
-    new_r, parts_r = fused_sweep_residual_halo_ref(
-        x, halos, b, coefs, tile=tile, op=op, linf=True)
+        x, halos, b, coefs, op=op, linf=True, interpret=True)
+    new_r, c_r = fused_sweep_residual_halo_ref(x, halos, b, coefs, op=op,
+                                               linf=True)
     np.testing.assert_allclose(np.asarray(new_k), np.asarray(new_r),
                                rtol=1e-12, atol=1e-12)
-    np.testing.assert_allclose(np.asarray(parts_k), np.asarray(parts_r),
+    np.testing.assert_allclose(float(reduce_partials(parts_k)), float(c_r),
                                rtol=1e-12, atol=1e-12)
 
 
+@pytest.mark.parametrize("block", HALO_BLOCKS)
 @pytest.mark.parametrize("oxyz", [0, 1, 5])
-def test_rbgs_halo_kernel_matches_oracle(oxyz):
+def test_rbgs_halo_kernel_matches_oracle(oxyz, block):
     from repro.kernels.jacobi3d.jacobi3d import fused_rbgs_sweep_residual_halo
-    from repro.kernels.jacobi3d.ref import ghosted6_ref, residual_partials
+    from repro.kernels.jacobi3d.ref import (
+        contribution,
+        ghosted6_ref,
+        reduce_partials,
+    )
     from repro.solvers import gauss_seidel
 
-    st, coefs, x, b, halos = _halo_setup()
+    st, coefs, x, b, halos = _halo_setup(*block)
     new_k, parts_k = fused_rbgs_sweep_residual_halo(
-        x, halos, b, coefs, jnp.int32(oxyz), tile=(4, 8), linf=True,
-        interpret=True)
+        x, halos, b, coefs, jnp.int32(oxyz), linf=True, interpret=True)
     g = ghosted6_ref(x, halos)
     new_r, rr = gauss_seidel.redblack_gs_sweep_residual(st, g, b, oxyz, 0, 0)
-    parts_r = residual_partials(rr, tile=(4, 8), linf=True)
     np.testing.assert_allclose(np.asarray(new_k), np.asarray(new_r),
                                rtol=1e-12, atol=1e-12)
-    np.testing.assert_allclose(np.asarray(parts_k), np.asarray(parts_r),
-                               rtol=1e-12, atol=1e-12)
+    np.testing.assert_allclose(float(reduce_partials(parts_k)),
+                               float(contribution(rr)), rtol=1e-12,
+                               atol=1e-12)
 
 
 def test_ops_halo_entries_match_ghosted_solvers_bitwise():

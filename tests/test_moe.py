@@ -75,9 +75,9 @@ def test_shard_map_moe_matches_local_reference_single_device():
     key = jax.random.PRNGKey(0)
     weights = moe_init(key, plan, gated=True, dtype=jnp.float32)
     x = jax.random.normal(jax.random.fold_in(key, 7), (2, 8, cfg.d_model))
-    from repro.core.compat import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     y_ref, aux_ref = moe_local_reference(x, weights, plan, gated=True)
     y_sm, aux_sm = jax.jit(
         lambda xx, ww: moe_mod.moe_apply(xx, ww, plan, True, mesh, dp_axes=("data",))
@@ -93,9 +93,9 @@ def test_moe_is_differentiable_through_dispatch():
     key = jax.random.PRNGKey(0)
     weights = moe_init(key, plan, gated=True, dtype=jnp.float32)
     x = jax.random.normal(jax.random.fold_in(key, 9), (1, 8, cfg.d_model))
-    from repro.core.compat import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
 
     def loss(w):
         y, aux = moe_mod.moe_apply(x, w, plan, True, mesh, dp_axes=("data",))

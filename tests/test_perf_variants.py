@@ -61,9 +61,9 @@ def test_save_mixer_remat_grad_parity():
 
 
 def test_tp_reduce_bf16_loss_parity_single_device_mesh():
-    from repro.core.compat import make_mesh_compat
+    from repro.launch.mesh import make_mesh
 
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = reduced(get_arch("qwen2-1.5b"))
     m1 = Model(cfg, mesh=mesh)
     m2 = Model(cfg, mesh=mesh, parallel=ParallelConfig(tp_reduce_bf16=True))

@@ -94,10 +94,10 @@ def test_make_shard_mesh_validates():
 
 
 def test_shard_axis_of_rejects_2d_mesh():
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_mesh
 
     with pytest.raises(ValueError, match="1-D"):
-        shard_axis_of(compat_make_mesh((1, 1), ("data", "model")))
+        shard_axis_of(make_mesh((1, 1), ("data", "model")))
 
 
 def test_convdiff_runtime_requires_divisible_n():

@@ -78,10 +78,10 @@ def test_hybrid_gs_converges_faster_than_jacobi():
 
 @pytest.mark.slow
 def test_sharded_solver_single_device_mesh_matches_single():
-    from repro.launch.mesh import compat_make_mesh
+    from repro.launch.mesh import make_mesh
 
     n = 12
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     st = Stencil.for_contraction(n, 1.0, (1.0, 1.0, 1.0), rho=0.9)
     b = jnp.asarray(make_rhs(n, 0))
     mon = detection.for_mode("pfait", eps_tilde=1e-8, margin=10.0,
