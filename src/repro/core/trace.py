@@ -36,6 +36,13 @@ launched global value), ``detect`` (detection claim), ``member``
 
 ``sim/replay.py`` consumes these traces; ``sim/calibrate.py`` fits delay
 models from them.
+
+**Device scopes.**  ``device_scope(kind)`` is the ``jax.named_scope``
+``repro.<kind>`` that the shard runtime opens around each phase of an
+outer step (``sweep``, ``halo``, ``reduce``, ``detect``).  Scopes are
+compile-time metadata: they reach every compiled op's ``op_name``, so a
+profiler trace of the device attributes op time by the same kinds as this
+schema, and the compiled program is otherwise unchanged.
 """
 from __future__ import annotations
 
@@ -49,6 +56,18 @@ EVENT_KINDS = ("sweep", "halo", "reduce", "detect", "member", "segment",
                "finish")
 
 _REQUIRED = ("kind", "t", "w", "step")
+
+#: prefix of the device scopes: ``repro.sweep`` … (``device_scope``)
+SCOPE_PREFIX = "repro."
+
+
+def device_scope(kind: str):
+    """``jax.named_scope(SCOPE_PREFIX + kind)`` for one event kind."""
+    if kind not in EVENT_KINDS:
+        raise ValueError(f"event kind {kind!r} not in {EVENT_KINDS}")
+    import jax
+
+    return jax.named_scope(SCOPE_PREFIX + kind)
 
 
 def event(kind: str, t: float, w: int = -1, step: int = -1,

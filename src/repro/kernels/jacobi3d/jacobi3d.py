@@ -43,6 +43,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.trace import device_scope
+
 #: VMEM bytes one x-slab may take; with its halo planes, double buffering
 #: and the stencil temporaries a kernel stays inside ``_VMEM_LIMIT``
 _SLAB_BYTES = 1 << 20
@@ -206,10 +208,12 @@ def _face_specs(bx, by, bz, tx):
     ]
 
 
+@device_scope("halo")
 def _face_arrays(halos, x):
     """The six face planes in block dtype, shaped so every slab takes a
     tile-legal block: x faces ``(1, by, bz)``, y faces ``(bx, 1, bz)``,
-    z faces ``(bx, by, 1)``."""
+    z faces ``(bx, by, 1)``.  Halo assembly: where a face is the constant
+    boundary, the compiler materialises it here, every call."""
     bx, by, bz = x.shape
     gxm, gxp, gym, gyp, gzm, gzp = (h.astype(x.dtype) for h in halos)
     return (gxm.reshape(1, by, bz), gxp.reshape(1, by, bz),

@@ -57,6 +57,7 @@ import numpy as np
 from repro.core import detection
 from repro.core import residual as res
 from repro.core.reduction import REDUCTIONS, get_reduction
+from repro.core.trace import device_scope
 from repro.kernels.jacobi3d import ops as jac_ops
 from repro.kernels.residual_norm import ops as rn_ops
 from repro.solvers import gauss_seidel, jacobi
@@ -275,45 +276,55 @@ def _make_loop(cfg: ShardRuntimeConfig, prob: _ShardProblem, p: int,
         my_lag = jnp.asarray(lag)[rank]
 
         def body(state):
+            # each phase runs under the device scope of its event kind
+            # (``repro.sweep`` …); a scope nested inside another wins
             x, gring, cring, partial, visible, mon, trace, k = state
-            ghosts = _ring_read(gring, k - my_delay)
+            with device_scope("halo"):
+                ghosts = _ring_read(gring, k - my_delay)
 
             def plain(_, xx):
                 return prob.sweep(xx, ghosts, *problem_args)
 
-            if cfg.reduction == "blocking":
-                x = jax.lax.fori_loop(0, my_inner, plain, x)
-                contrib = None
-                fresh = prob.exchange(x)
-            elif prob.fused_step is not None:
-                # comm-overlapped step: thin face slabs are swept first and
-                # shipped, then the full block sweeps against the *landed*
-                # ghosts — the collective and the interior pass commute
-                x = jax.lax.fori_loop(0, my_inner - 1, plain, x)
-                x, contrib, fresh = prob.fused_step(x, ghosts, *problem_args)
-            else:
-                x = jax.lax.fori_loop(0, my_inner - 1, plain, x)
-                x, contrib = prob.sweep_contrib(x, ghosts, *problem_args)
-                fresh = prob.exchange(x)
+            fresh = None
+            with device_scope("sweep"):
+                if cfg.reduction == "blocking":
+                    x = jax.lax.fori_loop(0, my_inner, plain, x)
+                    contrib = None
+                elif prob.fused_step is not None:
+                    # comm-overlapped step: thin face slabs are swept first
+                    # and shipped, then the full block sweeps against the
+                    # *landed* ghosts — the collective and the interior
+                    # pass commute
+                    x = jax.lax.fori_loop(0, my_inner - 1, plain, x)
+                    x, contrib, fresh = prob.fused_step(x, ghosts,
+                                                        *problem_args)
+                else:
+                    x = jax.lax.fori_loop(0, my_inner - 1, plain, x)
+                    x, contrib = prob.sweep_contrib(x, ghosts, *problem_args)
 
-            gring = _ring_write(gring, fresh, k + 1)
-            if contrib is None:
-                # barrier mode: detection pays a residual-only pass over the
-                # fresh post-exchange state, every check
-                contrib = prob.exact_contrib(x, fresh, *problem_args)
-            cring = _ring_write(cring, contrib, k)
-            lane = _ring_read(cring, k - my_lag)
+            with device_scope("halo"):
+                if fresh is None:
+                    fresh = prob.exchange(x)
+                gring = _ring_write(gring, fresh, k + 1)
+            with device_scope("reduce"):
+                if contrib is None:
+                    # barrier mode: detection pays a residual-only pass over
+                    # the fresh post-exchange state, every check
+                    contrib = prob.exact_contrib(x, fresh, *problem_args)
+                cring = _ring_write(cring, contrib, k)
+                lane = _ring_read(cring, k - my_lag)
 
-            if cfg.reduction == "rdoubling":
-                partial, visible = _butterfly_step(
-                    lane, partial, visible, k, p, axis, ord_)
-                g_pre = visible
-            else:
-                g_pre = _preduce(lane, axis, ord_)
+                if cfg.reduction == "rdoubling":
+                    partial, visible = _butterfly_step(
+                        lane, partial, visible, k, p, axis, ord_)
+                    g_pre = visible
+                else:
+                    g_pre = _preduce(lane, axis, ord_)
 
-            trace = trace.at[jnp.minimum(k, tlen - 1)].set(
-                jnp.where(k < tlen, res.sigma(g_pre, ord_).astype(jnp.float32),
-                          trace[jnp.minimum(k, tlen - 1)]))
+                trace = trace.at[jnp.minimum(k, tlen - 1)].set(
+                    jnp.where(k < tlen,
+                              res.sigma(g_pre, ord_).astype(jnp.float32),
+                              trace[jnp.minimum(k, tlen - 1)]))
 
             def exact_fn(x=x, fresh=fresh):
                 # NFAIS2's verification: a *blocking* exact reduction of the
@@ -321,15 +332,17 @@ def _make_loop(cfg: ShardRuntimeConfig, prob: _ShardProblem, p: int,
                 return res.psum_sigma(
                     prob.exact_contrib(x, fresh, *problem_args), axis, ord_)
 
-            mon = detection.step(mon_cfg, mon, g_pre, axis_names=None,
-                                 exact_residual_fn=exact_fn)
+            with device_scope("detect"):
+                mon = detection.step(mon_cfg, mon, g_pre, axis_names=None,
+                                     exact_residual_fn=exact_fn)
             return x, gring, cring, partial, visible, mon, trace, k + 1
 
         def cond(state):
             mon, k = state[5], state[7]
             return (~mon.converged) & (k < cfg.max_outer)
 
-        ghosts0 = prob.exchange(x0)
+        with device_scope("halo"):
+            ghosts0 = prob.exchange(x0)
         state0 = (
             x0,
             _ring_fill(ghosts0, Lg),
@@ -414,6 +427,7 @@ def _make_convdiff_mesh_runtime(cfg: ShardRuntimeConfig, mesh, stencil:
         return jax.lax.index_in_dim(x, x.shape[d] - 1 if last else 0, d,
                                     keepdims=False)
 
+    @device_scope("halo")
     def _ship(faces):
         """ppermute each partitioned direction's (minus, plus) face pair to
         the respective neighbours; edge shards receive zeros (Dirichlet)."""
@@ -429,6 +443,7 @@ def _make_convdiff_mesh_runtime(cfg: ShardRuntimeConfig, mesh, stencil:
         return _ship({d: (_face(x, d, False), _face(x, d, True))
                       for d in parted})
 
+    @device_scope("halo")
     def _halos6(x, faces):
         """Six face planes for the halo-consuming sweeps: exchanged ghosts
         on partitioned directions, zeros (physical BC) elsewhere."""
@@ -465,6 +480,7 @@ def _make_convdiff_mesh_runtime(cfg: ShardRuntimeConfig, mesh, stencil:
         return jac_ops.residual_contribution_halo(st, x, _halos6(x, faces),
                                                   b, ord=ord_)
 
+    @device_scope("halo")
     def _face_sweep(x, h6, b, d, last):
         """The new values of one face of the block, as the full Jacobi sweep
         will produce them, from a thickness-1 slab: the same sweep entry on
@@ -549,6 +565,7 @@ def make_convdiff_runtime(cfg: ShardRuntimeConfig, mesh, stencil: Stencil,
         gxp = _shift(x[0, :, :], axis, up=False, axis_size=p)
         return gxm, gxp
 
+    @device_scope("halo")
     def _ghosted(x, ghosts):
         gxm, gxp = ghosts
         zero = jnp.zeros((x.shape[0], x.shape[2]), x.dtype)

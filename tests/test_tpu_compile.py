@@ -92,3 +92,53 @@ def test_diff_norm_partials_compiles(one_chip, linf):
     s = one_chip
     _assert_kernel(diff_norm_partials.lower(
         _spec((N, N, N), s), _spec((N, N, N), s), linf=linf))
+
+
+@pytest.mark.parametrize("reduction,mode", [("blocking", "sync"),
+                                            ("nonblocking", "pfait")])
+def test_solve_kernels_sit_in_their_scopes(one_chip, monkeypatch, reduction,
+                                           mode):
+    """The whole (1,1)-mesh solve compiled for the chip: the red-black
+    kernel under ``repro.sweep``, the blocking mode's residual-only kernel
+    under ``repro.reduce``, and the zero faces the compiler materialises
+    for the kernels under ``repro.halo`` (a profiler trace reads the
+    scopes from each op's ``op_name``)."""
+    import re
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+
+    from repro.core import detection
+    from repro.runtime import shard_runtime as sr
+    from repro.solvers.convdiff import Stencil
+
+    # the kernel entries pick the Pallas path by the default backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    (device,) = one_chip.device_set
+    mesh = Mesh(np.array([device]).reshape(1, 1), ("shard_x", "shard_y"))
+    st = Stencil.for_contraction(N, 1.0, (1.0, 1.0, 1.0), rho=0.9)
+    mon = detection.for_mode(mode, eps_tilde=1e-4, margin=10.0,
+                             staleness=2 if reduction == "nonblocking" else 0)
+    cfg = sr.ShardRuntimeConfig(monitor=mon, reduction=reduction,
+                                sweep="hybrid", max_outer=50,
+                                mesh_shape=(1, 1))
+    spec = _spec((N, N, N), NamedSharding(mesh, sr.mesh_state_spec(
+        "convdiff", mesh)))
+    text = jax.jit(sr.make_convdiff_runtime(cfg, mesh, st, N)).lower(
+        spec, spec).compile().as_text()
+
+    def innermost(pattern):
+        """Innermost ``repro.`` scope of each instruction named so."""
+        out = set()
+        for m in re.finditer(r"^\s*(?:ROOT )?%" + pattern + r"\S* = .*"
+                             r'op_name="([^"]*)"', text, re.MULTILINE):
+            out.add(re.findall(r"repro\.(\w+)", m.group(1))[-1])
+        return out
+
+    assert innermost("fused_rbgs_sweep_residual_halo") == {"sweep"}
+    want = {"reduce"} if reduction == "blocking" else set()
+    assert innermost("fused_sweep_residual_halo") == want
+    faces = re.findall(r"^\s*%broadcast\S* = f32\[(?:1,\d+,\d+|\d+,1,\d+|"
+                       r'\d+,\d+,1)\].*op_name="([^"]*)"', text, re.MULTILINE)
+    assert faces and {re.findall(r"repro\.(\w+)", f)[-1]
+                      for f in faces} == {"halo"}
