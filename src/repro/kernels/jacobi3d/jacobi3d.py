@@ -7,10 +7,12 @@ partials — the stencil is memory-bound, so fusing the detection pass saves
 a second read of the field.  Two sweep flavours are fused:
 
 * ``fused_sweep_residual*``       — Jacobi sweep (±1 plane window);
-* ``fused_rbgs_sweep_residual*``  — the paper's hybrid red-black GS sweep
-  (±2 plane window: each slab recomputes the colour-0 updates of its two
-  neighbouring planes locally, so the two-colour dependency never crosses
-  grid steps and the sweep stays a single grid pass).
+* ``fused_rbgs_sweep_residual*``  — the paper's hybrid red-black GS sweep,
+  streamed along x through a rolling window of VMEM scratch: each plane of
+  ``x`` and ``b`` leaves HBM once, its colour-0 update is computed once
+  and kept until colour 1 of both its neighbours has used it, and the
+  output trails the input by two slabs, so the two-colour dependency
+  crosses grid steps inside VMEM and the sweep stays a single grid pass.
 
 Both report the residual of the *input* state (``b − A x_in``), i.e. the
 detection contribution is one sweep staler than a dedicated post-sweep pass
@@ -20,11 +22,13 @@ Layout.  A block is ``(bx, by, bz)`` with z on the 128-wide lanes and y on
 the sublanes.  The grid walks x-slabs of ``tx`` whole ``(by, bz)`` planes,
 so every y/z neighbour of a slab cell is in the slab: the ±1 shifts along
 sublanes/lanes are ``pltpu.roll`` rotations whose wrapped row/column is
-replaced by the face halo.  The x neighbours outside the slab arrive as
-extra single-plane blocks (index clamped into the block; the x∓ face halo
-substitutes at the block edge), so every input is a tile-legal block that
-Pallas pipelines HBM→VMEM itself.  ``tx`` follows from the plane size (see
-``_slab_planes``), which keeps VMEM bounded for blocks up to 512² planes.
+replaced by the face halo.  The x∓ face halo substitutes for the plane
+past the block edge.  The Jacobi kernel gets the x neighbours outside its
+slab as extra single-plane blocks (index clamped into the block); the
+RB-GS kernel's grid runs in order (``"arbitrary"``) and keeps them in its
+window.  Every input is a tile-legal block that Pallas pipelines HBM→VMEM
+itself.  ``tx`` follows from the plane size (see ``_slab_planes``), which
+keeps VMEM bounded for blocks up to 512² planes.
 The seven stencil coefficients and the checkerboard phase live in SMEM;
 each grid step writes its residual partial into its own lane-dense
 ``(8, 128)`` output tile.
@@ -50,8 +54,10 @@ from repro.core.trace import device_scope
 _SLAB_BYTES = 1 << 20
 #: most planes per slab: the per-plane loop is unrolled in the kernel body
 _MAX_PLANES = 8
-#: scoped VMEM per kernel: the RB-GS kernel on 512² planes needs more than
-#: the 16 MiB default and fits 32 MiB (v5e compile); v5e has 128 MiB
+#: scoped VMEM per kernel: the RB-GS kernel's window (three slabs of x and
+#: of colour-0 planes, two of b and face rows) and pipeline buffers on 512²
+#: planes need more than the 16 MiB default and fit 20 MiB; on 512×1024
+#: planes they fit 40 MiB (v5e compiles); v5e has 128 MiB
 _VMEM_LIMIT = 48 << 20
 #: partials tile: one lane-dense f32 (8, 128) block per grid step
 _PART = (8, 128)
@@ -128,23 +134,25 @@ def _jacobi_kernel(c_ref, x_ref, xm_ref, xp_ref, gxm_ref, gxp_ref, gym_ref,
     _write_partial(out_refs[-1], acc, linf)
 
 
-def _rbgs_kernel(c_ref, ph_ref, x_ref, xm2_ref, xm1_ref, xp1_ref, xp2_ref,
-                 gxm_ref, gxp_ref, gym_ref, gym_m_ref, gym_p_ref, gyp_ref,
-                 gyp_m_ref, gyp_p_ref, gzm_ref, gzm_m_ref, gzm_p_ref, gzp_ref,
-                 gzp_m_ref, gzp_p_ref, b_ref, bm_ref, bp_ref, new_ref, res_ref,
-                 *, linf: bool, bx: int):
-    """Hybrid red-black GS sweep fused with the pre-sweep residual over one
-    x-slab.  Planes ``x0-2 … x0+tx+1`` are in view: the slab's neighbouring
-    planes ``x0-1``/``x0+tx`` get their colour-0 update recomputed here
-    (ghost planes stay frozen), so colour 1 on the slab sees same-sweep
-    colour-0 values without waiting on another grid step."""
-    i = pl.program_id(0)
-    tx = x_ref.shape[0]
-    x0 = i * tx
-    dtype = x_ref.dtype
-    k = _coefs(c_ref, dtype)
+def _rbgs_kernel(c_ref, ph_ref, x_ref, gxm_ref, gxp_ref, gym_ref, gyp_ref,
+                 gzm_ref, gzp_ref, b_ref, new_ref, res_ref, xw, uw, bw, yw,
+                 zw, *, linf: bool, nx: int):
+    """Hybrid red-black GS sweep fused with the pre-sweep residual, streamed
+    along x through a rolling window of VMEM scratch.
+
+    Grid step ``j`` brings slab ``j`` (``tx`` planes of ``x``, ``b`` and
+    their y/z face rows); step ``j`` computes colour 0 and the residual of
+    slab ``j-1``, whose x neighbours are then all in view, and colour 1 of
+    slab ``j-2``, whose neighbours' colour-0 values then exist.  The
+    window: ``xw`` holds the ``x`` slabs ``j-2 … j`` (3 slots), ``uw`` the
+    colour-0 slabs ``j-3 … j-1`` (3 slots), ``bw``/``yw``/``zw`` the ``b``
+    and face rows of slabs ``j-2, j-1`` (2 slots; slab ``j`` replaces
+    ``j-2`` after its last use).  The x∓ face halo fills the slot a plane
+    past the block edge would take (ghost planes stay frozen)."""
+    j = pl.program_id(0)
+    tx, by, bz = x_ref.shape
+    k = _coefs(c_ref, x_ref.dtype)
     diag = k[0]
-    _, by, bz = x_ref.shape
     yz = (jax.lax.broadcasted_iota(jnp.int32, (by, bz), 0)
           + jax.lax.broadcasted_iota(jnp.int32, (by, bz), 1) + ph_ref[0])
 
@@ -152,43 +160,61 @@ def _rbgs_kernel(c_ref, ph_ref, x_ref, xm2_ref, xm1_ref, xp1_ref, xp2_ref,
         """Colour-0 mask of the plane at global row ``gx``."""
         return jnp.bitwise_and(yz + gx, 1) == 0
 
-    def outside(gx, ref):
-        """Plane ``gx`` outside the slab: the block's own plane, the x∓
-        face halo one step past the edge (anything further is dead)."""
-        ghost = jnp.where(gx < 0, gxm_ref[0], gxp_ref[0])
-        return jnp.where((gx >= 0) & (gx < bx), ref[0], ghost)
+    def slot(n, q):
+        return jax.lax.rem(j + n, np.int32(q))
 
-    # input state of planes x0-2 … x0+tx+1
-    planes = ([outside(x0 - 2, xm2_ref), outside(x0 - 1, xm1_ref)]
-              + [x_ref[t] for t in range(tx)]
-              + [outside(x0 + tx, xp1_ref), outside(x0 + tx + 1, xp2_ref)])
-    yhalo = ([(gym_m_ref[0], gyp_m_ref[0], gzm_m_ref[0], gzp_m_ref[0])]
-             + [(gym_ref[t], gyp_ref[t], gzm_ref[t], gzp_ref[t])
-                for t in range(tx)]
-             + [(gym_p_ref[0], gyp_p_ref[0], gzm_p_ref[0], gzp_p_ref[0])])
-    bs = [bm_ref[0]] + [b_ref[t] for t in range(tx)] + [bp_ref[0]]
+    def halo(s, t):
+        return yw[s, 0, t], yw[s, 1, t], zw[s, 0, t], zw[s, 1, t]
 
-    # colour 0 on planes x0-1 … x0+tx (index s = plane - x0 + 1), with the
-    # slab's residual from the same off-diagonal apply
-    upd0 = []
-    acc = None
-    for s in range(tx + 2):
-        c = planes[s + 1]
-        off = _plane_off(c, planes[s], planes[s + 2], *yhalo[s], k)
-        u = jnp.where(even(x0 + s - 1), (bs[s] - off) / diag, c)
-        if s == 0 or s == tx + 1:
-            gx = x0 + s - 1
-            u = jnp.where((gx >= 0) & (gx < bx), u, c)   # ghosts stay frozen
-        else:
-            acc = _accumulate(acc, bs[s] - (diag * c + off), linf)
-        upd0.append(u)
+    @pl.when(j < nx)
+    def _():   # slab j joins the x window
+        xw[slot(0, 3)] = x_ref[...]
 
-    # colour 1 on the slab, against same-sweep colour-0 values
-    for t in range(tx):
-        s = t + 1
-        off1 = _plane_off(upd0[s], upd0[s - 1], upd0[s + 1], *yhalo[s], k)
-        new_ref[t] = jnp.where(even(x0 + t), upd0[s], (bs[s] - off1) / diag)
-    _write_partial(res_ref, acc, linf)
+    @pl.when(j == 0)
+    def _():   # the plane before the block, for colour 0 and colour 1
+        xw[2, tx - 1] = gxm_ref[0]
+        uw[2, tx - 1] = gxm_ref[0]
+
+    @pl.when(j == nx)
+    def _():   # the plane after the block, for colour 0
+        xw[nx % 3, 0] = gxp_ref[0]
+
+    @pl.when(j == nx + 1)
+    def _():   # the plane after the block, for colour 1
+        uw[nx % 3, 0] = gxp_ref[0]
+
+    @pl.when((j >= 1) & (j <= nx))
+    def _():   # colour 0 and the input-state residual of slab j-1
+        x0 = (j - 1) * tx
+        sx, sb = slot(2, 3), slot(1, 2)
+        acc = None
+        for t in range(tx):
+            c = xw[sx, t]
+            xm = xw[sx, t - 1] if t else xw[slot(1, 3), tx - 1]
+            xp = xw[sx, t + 1] if t < tx - 1 else xw[slot(0, 3), 0]
+            off = _plane_off(c, xm, xp, *halo(sb, t), k)
+            b = bw[sb, t]
+            acc = _accumulate(acc, b - (diag * c + off), linf)
+            uw[sx, t] = jnp.where(even(x0 + t), (b - off) / diag, c)
+        _write_partial(res_ref, acc, linf)
+
+    @pl.when(j >= 2)
+    def _():   # colour 1 of slab j-2, against same-sweep colour-0 values
+        x0 = (j - 2) * tx
+        su, sb = slot(1, 3), slot(0, 2)
+        for t in range(tx):
+            u = uw[su, t]
+            um = uw[su, t - 1] if t else uw[slot(0, 3), tx - 1]
+            up = uw[su, t + 1] if t < tx - 1 else uw[slot(2, 3), 0]
+            off1 = _plane_off(u, um, up, *halo(sb, t), k)
+            new_ref[t] = jnp.where(even(x0 + t), u, (bw[sb, t] - off1) / diag)
+
+    @pl.when(j < nx)
+    def _():   # slab j's b and face rows replace slab j-2's
+        s = slot(0, 2)
+        bw[s] = b_ref[...]
+        yw[s, 0], yw[s, 1] = gym_ref[...], gyp_ref[...]
+        zw[s, 0], zw[s, 1] = gzm_ref[...], gzp_ref[...]
 
 
 # ---------------------------------------------------------------------------
@@ -196,15 +222,16 @@ def _rbgs_kernel(c_ref, ph_ref, x_ref, xm2_ref, xm1_ref, xp1_ref, xp2_ref,
 # ---------------------------------------------------------------------------
 
 
-def _face_specs(bx, by, bz, tx):
-    """Blocks of the six face planes, reshaped by ``_face_arrays``."""
+def _face_specs(by, bz, tx, slab=lambda i: i):
+    """Blocks of the six face planes, reshaped by ``_face_arrays``; grid
+    step ``i`` takes the y/z face rows of x-slab ``slab(i)``."""
     return [
         pl.BlockSpec((1, by, bz), lambda i: (0, 0, 0)),       # gxm
         pl.BlockSpec((1, by, bz), lambda i: (0, 0, 0)),       # gxp
-        pl.BlockSpec((tx, 1, bz), lambda i: (i, 0, 0)),       # gym
-        pl.BlockSpec((tx, 1, bz), lambda i: (i, 0, 0)),       # gyp
-        pl.BlockSpec((tx, by, 1), lambda i: (i, 0, 0)),       # gzm
-        pl.BlockSpec((tx, by, 1), lambda i: (i, 0, 0)),       # gzp
+        pl.BlockSpec((tx, 1, bz), lambda i: (slab(i), 0, 0)),  # gym
+        pl.BlockSpec((tx, 1, bz), lambda i: (slab(i), 0, 0)),  # gyp
+        pl.BlockSpec((tx, by, 1), lambda i: (slab(i), 0, 0)),  # gzm
+        pl.BlockSpec((tx, by, 1), lambda i: (slab(i), 0, 0)),  # gzp
     ]
 
 
@@ -230,8 +257,8 @@ def _partials(res):
     return res[::_PART[0], 0]
 
 
-def _params():
-    return pltpu.CompilerParams(dimension_semantics=("parallel",),
+def _params(semantics="parallel"):
+    return pltpu.CompilerParams(dimension_semantics=(semantics,),
                                 vmem_limit_bytes=_VMEM_LIMIT)
 
 
@@ -270,7 +297,7 @@ def fused_sweep_residual_halo(
             slab,
             _plane_spec(by, bz, lambda i: jnp.maximum(i * tx - 1, 0)),
             _plane_spec(by, bz, lambda i: jnp.minimum((i + 1) * tx, bx - 1)),
-            *_face_specs(bx, by, bz, tx),
+            *_face_specs(by, bz, tx),
             slab,
         ],
         out_specs=[slab, part] if sweep else [part],
@@ -294,7 +321,8 @@ def fused_rbgs_sweep_residual_halo(
     interpret: bool = False,
 ):
     """Hybrid RB-GS sweep + pre-sweep residual partials from an unghosted
-    block and explicit halo planes, in one grid pass.
+    block and explicit halo planes, in one grid pass that reads each plane
+    of ``x`` and ``b`` once (see ``_rbgs_kernel``).
 
     Returns ``(new_block [bx,by,bz], residual partials [nx])`` where the
     partials reduce ``b − A x_in`` (the *input* state's residual — the free
@@ -302,46 +330,38 @@ def fused_rbgs_sweep_residual_halo(
     bx, by, bz = x.shape
     tx = _slab_planes(bx, by, bz, x.dtype.itemsize)
     nx = bx // tx
+    dtype = x.dtype
 
-    def plane(offset):
-        return _plane_spec(
-            by, bz, lambda i: jnp.clip(i * tx + offset, 0, bx - 1))
+    # step j reads slab j, writes the partial of slab j-1 and the new
+    # planes of slab j-2: two steps past the last slab drain the window
+    def lead(j):
+        return jnp.minimum(j, nx - 1)
 
-    def row(shape, offset):
-        return pl.BlockSpec(
-            shape, lambda i: (jnp.clip(i * tx + offset, 0, bx - 1), 0, 0))
-
-    faces = _face_arrays(halos, x)
-    fx, fy, fz = faces[:2], faces[2:4], faces[4:]
-    specs = _face_specs(bx, by, bz, tx)
-    slab = pl.BlockSpec((tx, by, bz), lambda i: (i, 0, 0))
-    yrow, zcol = (1, 1, bz), (1, by, 1)
+    slab = pl.BlockSpec((tx, by, bz), lambda j: (lead(j), 0, 0))
     new, res = pl.pallas_call(
-        functools.partial(_rbgs_kernel, linf=linf, bx=bx),
-        grid=(nx,),
-        in_specs=[
-            _SMEM, _SMEM,
-            slab, plane(-2), plane(-1), plane(tx), plane(tx + 1),
-            *specs[:2],
-            specs[2], row(yrow, -1), row(yrow, tx),
-            specs[3], row(yrow, -1), row(yrow, tx),
-            specs[4], row(zcol, -1), row(zcol, tx),
-            specs[5], row(zcol, -1), row(zcol, tx),
-            slab, plane(-1), plane(tx),
+        functools.partial(_rbgs_kernel, linf=linf, nx=nx),
+        grid=(nx + 2,),
+        in_specs=[_SMEM, _SMEM, slab, *_face_specs(by, bz, tx, lead), slab],
+        out_specs=[
+            pl.BlockSpec((tx, by, bz), lambda j: (jnp.maximum(j - 2, 0), 0, 0)),
+            pl.BlockSpec(_PART, lambda j: (jnp.clip(j - 1, 0, nx - 1), 0)),
         ],
-        out_specs=[slab, pl.BlockSpec(_PART, lambda i: (i, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct(x.shape, x.dtype),
+            jax.ShapeDtypeStruct(x.shape, dtype),
             jax.ShapeDtypeStruct((nx * _PART[0], _PART[1]), jnp.float32),
         ],
-        compiler_params=_params(),
+        scratch_shapes=[
+            pltpu.VMEM((3, tx, by, bz), dtype),       # x window
+            pltpu.VMEM((3, tx, by, bz), dtype),       # colour-0 window
+            pltpu.VMEM((2, tx, by, bz), dtype),       # b
+            pltpu.VMEM((2, 2, tx, 1, bz), dtype),     # y face rows
+            pltpu.VMEM((2, 2, tx, by, 1), dtype),     # z face columns
+        ],
+        compiler_params=_params("arbitrary"),
         interpret=interpret,
-    )(stencil_coefs.astype(x.dtype),
+    )(stencil_coefs.astype(dtype),
       jnp.asarray(oxyz, jnp.int32).reshape((1,)),
-      x, x, x, x, x, *fx,
-      fy[0], fy[0], fy[0], fy[1], fy[1], fy[1],
-      fz[0], fz[0], fz[0], fz[1], fz[1], fz[1],
-      *(b.astype(x.dtype),) * 3)
+      x, *_face_arrays(halos, x), b.astype(dtype))
     return new, _partials(res)
 
 

@@ -15,7 +15,7 @@ import pytest
 
 from repro.core import detection
 from repro.kernels.jacobi3d import ops as jac_ops
-from repro.kernels.jacobi3d.jacobi3d import fused_rbgs_sweep_residual
+from repro.kernels.jacobi3d.jacobi3d import _slab_planes, fused_rbgs_sweep_residual
 from repro.kernels.jacobi3d.ref import contribution, reduce_partials
 from repro.solvers import gauss_seidel, jacobi
 from repro.solvers.convdiff import ConvDiffProblem, Stencil, make_rhs
@@ -93,11 +93,24 @@ def test_sweep_with_contribution_matches_separate_passes(sweep, ordv):
 @pytest.mark.parametrize("ox,oy", [(0, 0), (3, 5), (6, 2)])
 @pytest.mark.parametrize("linf", [True, False])
 def test_rbgs_kernel_interpret_matches_oracle(ox, oy, linf):
-    """Pallas single-pass hybrid kernel (±2 plane window, interpret=True) vs
-    the pure-jnp oracle — two x-slabs exercise the cross-slab colour
-    dependency."""
+    """Pallas single-pass hybrid kernel (interpret=True) vs the pure-jnp
+    oracle — two x-slabs exercise the cross-slab colour dependency."""
+    _check_rbgs_kernel((16, 8, 8), ox, oy, linf)
+
+
+@pytest.mark.parametrize("block", [(5, 256, 256), (24, 8, 8)])
+@pytest.mark.parametrize("ox,oy", [(0, 0), (3, 5), (6, 2)])
+@pytest.mark.parametrize("linf", [True, False])
+def test_rbgs_kernel_window_warmup_and_drain(block, ox, oy, linf):
+    """The streamed kernel's window fills and drains at the block's x
+    faces: one-plane slabs (a 512 KiB f64 plane), five of them, and three
+    8-plane slabs, the middle one with neighbours on both sides."""
+    _check_rbgs_kernel(block, ox, oy, linf)
+
+
+def _check_rbgs_kernel(block, ox, oy, linf):
     st = Stencil.for_contraction(8, 1.0, (1.0, 1.0, 1.0), 0.9)
-    bx, by, bz = 16, 8, 8
+    bx, by, bz = block
     x = jnp.asarray(RNG.standard_normal((bx, by, bz)))
     b = jnp.asarray(RNG.standard_normal((bx, by, bz)))
     ghosts = tuple(jnp.asarray(RNG.standard_normal(s))
@@ -108,7 +121,7 @@ def test_rbgs_kernel_interpret_matches_oracle(ox, oy, linf):
         jac_ops.ghost_pad2(x, ghosts), jnp.pad(b, ((1, 1), (1, 1), (0, 0))),
         jac_ops._coefs(st).astype(b.dtype), jnp.int32(ox + oy),
         linf=linf, interpret=True)
-    assert parts_k.shape == (2,)
+    assert parts_k.shape == (bx // _slab_planes(bx, by, bz, x.dtype.itemsize),)
     np.testing.assert_allclose(np.asarray(new_k), np.asarray(new_ref), atol=1e-12)
     np.testing.assert_allclose(float(reduce_partials(parts_k, linf)),
                                float(contribution(r_ref, linf)),

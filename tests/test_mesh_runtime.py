@@ -138,8 +138,8 @@ def _halo_setup(bx=8, by=8, bz=8, dtype=jnp.float64):
     return st, coefs, x, b, halos
 
 
-#: block shapes: one x-slab, two slabs, and one-plane slabs (a 512 KiB f64
-#: plane) whose ±2 window reaches the far face halo
+#: block shapes: one x-slab, two slabs, and three one-plane slabs (a
+#: 512 KiB f64 plane) whose x neighbours reach the face halos
 HALO_BLOCKS = [(8, 8, 8), (16, 8, 8), (3, 256, 256)]
 
 
@@ -163,7 +163,13 @@ def test_halo_kernel_matches_oracle(block, op):
                                rtol=1e-12, atol=1e-12)
 
 
-@pytest.mark.parametrize("block", HALO_BLOCKS)
+#: the red-black kernel streams the block through a window that lags the
+#: slab it reads by two: one-plane slabs whose lag reaches both x faces,
+#: and three 8-plane slabs, the middle one with neighbours on both sides
+RBGS_BLOCKS = HALO_BLOCKS + [(5, 256, 256), (24, 8, 8)]
+
+
+@pytest.mark.parametrize("block", RBGS_BLOCKS)
 @pytest.mark.parametrize("oxyz", [0, 1, 5])
 def test_rbgs_halo_kernel_matches_oracle(oxyz, block):
     from repro.kernels.jacobi3d.jacobi3d import fused_rbgs_sweep_residual_halo

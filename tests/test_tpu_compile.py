@@ -3,7 +3,8 @@
 Interpret mode cannot see what the chip's compiler refuses (tile-illegal
 blocks, vector loads from HBM refs, VMEM overuse), so these tests compile
 each kernel entry ahead of time for a described ``v5e:2x2`` topology — no
-chip attached — at n=256 f32 and check that the program holds the Mosaic
+chip attached — at n=256 f32 (the red-black kernel also at the
+benchmark's n=512) and check that the program holds the Mosaic
 kernel (``tpu_custom_call``).  The topology is described only inside the
 fixture: only the worker that runs these tests loads the TPU compiler.
 """
@@ -66,10 +67,16 @@ def test_jacobi_halo_compiles(one_chip, op):
         _spec((7,), s), op=op))
 
 
-def test_rbgs_halo_compiles(one_chip):
+#: 256: four planes per slab; 512: the benchmark's block, one plane per
+#: slab, so the rolling window's VMEM scratch is sized for 1 MiB planes
+RBGS_N = [256, 512]
+
+
+@pytest.mark.parametrize("n", RBGS_N)
+def test_rbgs_halo_compiles(one_chip, n):
     s = one_chip
     _assert_kernel(jacobi3d.fused_rbgs_sweep_residual_halo.lower(
-        _spec((N, N, N), s), _halos(s), _spec((N, N, N), s),
+        _spec((n, n, n), s), _halos(s, n), _spec((n, n, n), s),
         _spec((7,), s), _spec((), s, jnp.int32), linf=False))
 
 
@@ -80,10 +87,11 @@ def test_jacobi_ghosted_compiles(one_chip):
         _spec((7,), s)))
 
 
-def test_rbgs_ghosted_compiles(one_chip):
+@pytest.mark.parametrize("n", RBGS_N)
+def test_rbgs_ghosted_compiles(one_chip, n):
     s = one_chip
     _assert_kernel(jacobi3d.fused_rbgs_sweep_residual.lower(
-        _spec((N + 4, N + 4, N + 2), s), _spec((N + 2, N + 2, N), s),
+        _spec((n + 4, n + 4, n + 2), s), _spec((n + 2, n + 2, n), s),
         _spec((7,), s), _spec((), s, jnp.int32)))
 
 
