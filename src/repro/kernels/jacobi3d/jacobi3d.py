@@ -148,7 +148,22 @@ def _rbgs_kernel(c_ref, ph_ref, x_ref, gxm_ref, gxp_ref, gym_ref, gyp_ref,
     colour-0 slabs ``j-3 … j-1`` (3 slots), ``bw``/``yw``/``zw`` the ``b``
     and face rows of slabs ``j-2, j-1`` (2 slots; slab ``j`` replaces
     ``j-2`` after its last use).  The x∓ face halo fills the slot a plane
-    past the block edge would take (ghost planes stay frozen)."""
+    past the block edge would take (ghost planes stay frozen).
+
+    The new block is written in place over ``x`` (the wrapper aliases
+    them), and no slab is read after its new values land:
+
+    * slab ``j`` of ``x`` is fetched once, for step ``lead(j) = min(j,
+      nx-1)``; the drain steps ``nx, nx+1`` keep that block index, so
+      nothing is fetched again;
+    * the output block of step ``j`` is slab ``j-2``, written back to HBM
+      only when the output block index changes, i.e. after step ``j >= 2``;
+      steps 0 and 1 keep index 0 and write nothing;
+    * so slab ``s`` is read before step ``s`` and overwritten after step
+      ``s + 2``, and every later read of it comes from the window;
+    * the x∓ faces and the y/z face rows are arrays of their own (zeros
+      on a one-chip mesh, ghost-ring slots on a larger one), never views
+      of ``x``."""
     j = pl.program_id(0)
     tx, by, bz = x_ref.shape
     k = _coefs(c_ref, x_ref.dtype)
@@ -280,6 +295,9 @@ def fused_sweep_residual_halo(
     an unghosted block and explicit halo planes for every face — no
     host-side ghost assembly.
 
+    The new block does not alias ``x``: step ``i`` reads plane ``i·tx-1``
+    as an extra block after step ``i-1`` has written it.
+
     Returns ``(new_block [bx,by,bz], residual partials [nx])`` (one partial
     per x-slab: max|r| for l∞, Σr² for l2, in f32)."""
     bx, by, bz = x.shape
@@ -322,7 +340,10 @@ def fused_rbgs_sweep_residual_halo(
 ):
     """Hybrid RB-GS sweep + pre-sweep residual partials from an unghosted
     block and explicit halo planes, in one grid pass that reads each plane
-    of ``x`` and ``b`` once (see ``_rbgs_kernel``).
+    of ``x`` and ``b`` once (see ``_rbgs_kernel``).  The new block aliases
+    ``x``: inside a loop whose carry is ``x``, the kernel writes it in
+    place and XLA copies nothing; a caller that keeps ``x`` (an undonated
+    jit argument) gets a copy made before the call, so ``x`` stays valid.
 
     Returns ``(new_block [bx,by,bz], residual partials [nx])`` where the
     partials reduce ``b − A x_in`` (the *input* state's residual — the free
@@ -357,6 +378,7 @@ def fused_rbgs_sweep_residual_halo(
             pltpu.VMEM((2, 2, tx, 1, bz), dtype),     # y face rows
             pltpu.VMEM((2, 2, tx, by, 1), dtype),     # z face columns
         ],
+        input_output_aliases={2: 0},   # x -> new, see ``_rbgs_kernel``
         compiler_params=_params("arbitrary"),
         interpret=interpret,
     )(stencil_coefs.astype(dtype),

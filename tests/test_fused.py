@@ -7,6 +7,7 @@ Covers the three layers of the fusion:
                                residual-only second pass (PASS_COUNTS + HLO bytes)
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -15,8 +16,12 @@ import pytest
 
 from repro.core import detection
 from repro.kernels.jacobi3d import ops as jac_ops
-from repro.kernels.jacobi3d.jacobi3d import _slab_planes, fused_rbgs_sweep_residual
-from repro.kernels.jacobi3d.ref import contribution, reduce_partials
+from repro.kernels.jacobi3d.jacobi3d import (
+    _slab_planes,
+    fused_rbgs_sweep_residual,
+    fused_rbgs_sweep_residual_halo,
+)
+from repro.kernels.jacobi3d.ref import contribution, ghosted6_ref, reduce_partials
 from repro.solvers import gauss_seidel, jacobi
 from repro.solvers.convdiff import ConvDiffProblem, Stencil, make_rhs
 from repro.solvers.fixed_point import SolverConfig, make_sharded_solver, solve_single
@@ -106,6 +111,35 @@ def test_rbgs_kernel_window_warmup_and_drain(block, ox, oy, linf):
     faces: one-plane slabs (a 512 KiB f64 plane), five of them, and three
     8-plane slabs, the middle one with neighbours on both sides."""
     _check_rbgs_kernel(block, ox, oy, linf)
+
+
+@pytest.mark.parametrize("donate", [True, False])
+def test_rbgs_kernel_writes_over_its_input(donate):
+    """The new block aliases ``x``: with ``x`` donated the kernel writes
+    it in place, without, the caller's ``x`` survives the call; both give
+    the oracle's sweep.  Three 8-plane slabs, so the output trails the
+    input across slab boundaries."""
+    st = Stencil.for_contraction(8, 1.0, (1.0, 1.0, 1.0), 0.9)
+    bx, by, bz = 24, 8, 8
+    x_np = RNG.standard_normal((bx, by, bz))
+    b = jnp.asarray(RNG.standard_normal((bx, by, bz)))
+    halos = tuple(jnp.asarray(RNG.standard_normal(s)) for s in
+                  ((by, bz), (by, bz), (bx, bz), (bx, bz), (bx, by), (bx, by)))
+    x = jnp.asarray(x_np)
+    new_ref, r_ref = gauss_seidel.redblack_gs_sweep_residual(
+        st, ghosted6_ref(x, halos), b, 3, 0, 0)
+    sweep = jax.jit(functools.partial(fused_rbgs_sweep_residual_halo,
+                                      interpret=True),
+                    donate_argnums=(0,) if donate else ())
+    new_k, parts_k = sweep(x, halos, b, jac_ops._coefs(st).astype(b.dtype),
+                           jnp.int32(3))
+    np.testing.assert_allclose(np.asarray(new_k), np.asarray(new_ref),
+                               atol=1e-12)
+    np.testing.assert_allclose(float(reduce_partials(parts_k)),
+                               float(contribution(r_ref)),
+                               rtol=1e-5, atol=1e-9)
+    if not donate:
+        np.testing.assert_array_equal(np.asarray(x), x_np)
 
 
 def _check_rbgs_kernel(block, ox, oy, linf):

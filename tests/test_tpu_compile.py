@@ -5,10 +5,13 @@ blocks, vector loads from HBM refs, VMEM overuse), so these tests compile
 each kernel entry ahead of time for a described ``v5e:2x2`` topology — no
 chip attached — at n=256 f32 (the red-black kernel also at the
 benchmark's n=512) and check that the program holds the Mosaic
-kernel (``tpu_custom_call``).  The topology is described only inside the
-fixture: only the worker that runs these tests loads the TPU compiler.
+kernel (``tpu_custom_call``).  Whole (1,1)-mesh solves compiled the
+same way show where the kernels sit and what the while loop copies.  The
+topology is described only inside the fixture: only the worker that runs
+these tests loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -102,8 +105,40 @@ def test_diff_norm_partials_compiles(one_chip, linf):
         _spec((N, N, N), s), _spec((N, N, N), s), linf=linf))
 
 
-@pytest.mark.parametrize("reduction,mode", [("blocking", "sync"),
-                                            ("nonblocking", "pfait")])
+def _mesh_solve_text(one_chip, monkeypatch, n, reduction, mode):
+    """Optimised HLO of the hybrid convdiff solve on a (1,1) mesh, built
+    as the benchmark's cells build it, with the kernel path forced on (the
+    test process's backend is the CPU)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding
+
+    from repro.core import detection
+    from repro.kernels.jacobi3d import ops as jac_ops
+    from repro.launch.mesh import shard_axis_names
+    from repro.runtime import shard_runtime as sr
+    from repro.solvers.convdiff import Stencil
+
+    monkeypatch.setattr(jac_ops, "_use_kernel", lambda interpret: True)
+    (device,) = one_chip.device_set
+    mesh = Mesh(np.array([device]).reshape(1, 1),
+                shard_axis_names("shard", 2))
+    st = Stencil.for_contraction(n, 1.0, (1.0, 1.0, 1.0), rho=0.9)
+    mon = detection.for_mode(mode, eps_tilde=1e-4, margin=10.0,
+                             ord=float("inf"),
+                             staleness=2 if reduction == "nonblocking" else 0)
+    cfg = sr.ShardRuntimeConfig(monitor=mon, reduction=reduction,
+                                sweep="hybrid", max_outer=2000,
+                                mesh_shape=(1, 1))
+    spec = _spec((n, n, n), NamedSharding(mesh, sr.mesh_state_spec(
+        "convdiff", mesh)))
+    return jax.jit(sr.make_convdiff_runtime(cfg, mesh, st, n)).lower(
+        spec, spec).compile().as_text()
+
+
+SOLVE_MODES = [("blocking", "sync"), ("nonblocking", "pfait")]
+
+
+@pytest.mark.parametrize("reduction,mode", SOLVE_MODES)
 def test_solve_kernels_sit_in_their_scopes(one_chip, monkeypatch, reduction,
                                            mode):
     """The whole (1,1)-mesh solve compiled for the chip: the red-black
@@ -111,29 +146,7 @@ def test_solve_kernels_sit_in_their_scopes(one_chip, monkeypatch, reduction,
     under ``repro.reduce``, and the zero faces the compiler materialises
     for the kernels under ``repro.halo`` (a profiler trace reads the
     scopes from each op's ``op_name``)."""
-    import re
-
-    import numpy as np
-    from jax.sharding import Mesh, NamedSharding
-
-    from repro.core import detection
-    from repro.runtime import shard_runtime as sr
-    from repro.solvers.convdiff import Stencil
-
-    # the kernel entries pick the Pallas path by the default backend
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    (device,) = one_chip.device_set
-    mesh = Mesh(np.array([device]).reshape(1, 1), ("shard_x", "shard_y"))
-    st = Stencil.for_contraction(N, 1.0, (1.0, 1.0, 1.0), rho=0.9)
-    mon = detection.for_mode(mode, eps_tilde=1e-4, margin=10.0,
-                             staleness=2 if reduction == "nonblocking" else 0)
-    cfg = sr.ShardRuntimeConfig(monitor=mon, reduction=reduction,
-                                sweep="hybrid", max_outer=50,
-                                mesh_shape=(1, 1))
-    spec = _spec((N, N, N), NamedSharding(mesh, sr.mesh_state_spec(
-        "convdiff", mesh)))
-    text = jax.jit(sr.make_convdiff_runtime(cfg, mesh, st, N)).lower(
-        spec, spec).compile().as_text()
+    text = _mesh_solve_text(one_chip, monkeypatch, N, reduction, mode)
 
     def innermost(pattern):
         """Innermost ``repro.`` scope of each instruction named so."""
@@ -150,3 +163,27 @@ def test_solve_kernels_sit_in_their_scopes(one_chip, monkeypatch, reduction,
                        r'\d+,\d+,1)\].*op_name="([^"]*)"', text, re.MULTILINE)
     assert faces and {re.findall(r"repro\.(\w+)", f)[-1]
                       for f in faces} == {"halo"}
+
+
+@pytest.mark.parametrize("reduction,mode", SOLVE_MODES)
+def test_solve_loop_copies_no_block(one_chip, monkeypatch, reduction, mode):
+    """The benchmark's 512³ solve compiled for the chip: the red-black
+    kernel writes its new block over its input
+    (``output_to_operand_aliasing``), so the while loop carries ``x``
+    without copying it; the one block-sized copy left is the pre-loop
+    copy of the undonated ``x0``."""
+    n = 512
+    text = _mesh_solve_text(one_chip, monkeypatch, n, reduction, mode)
+    rbgs = re.findall(r"^\s*%fused_rbgs_sweep_residual_halo\S* = .*$", text,
+                      re.MULTILINE)
+    assert rbgs and all("output_to_operand_aliasing={{0}: (2, {})}" in line
+                        for line in rbgs)
+    copies = []     # (computation, instruction) of each block-sized copy
+    computation = None
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            computation = "ENTRY" if head.group(1) else head.group(2)
+        elif re.search(rf"= f32\[{n},{n},{n}\]\S* copy\(", line):
+            copies.append((computation, line.split("=")[0].strip()))
+    assert len(copies) == 1 and copies[0][0] == "ENTRY", copies
